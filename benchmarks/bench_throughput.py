@@ -343,7 +343,9 @@ for nl in n_locals:
 def run_local_sweep(quick: bool = False):
     """Step time vs partitions-per-device at fixed P=8: the same 8-partition
     graph on 8, 4, 2 (and 1) forced host devices. Needs its own process so
-    the forced device count doesn't leak into the caller's jax runtime."""
+    the forced device count doesn't leak into the caller's jax runtime; the
+    child is pinned to the CPU, so on an accelerator host it never competes
+    with its parent for the chip."""
     import os
     import subprocess
     import sys
@@ -353,6 +355,7 @@ def run_local_sweep(quick: bool = False):
     iters = 2 if quick else 4
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
         [sys.executable, "-c", _LOCAL_SWEEP_SCRIPT, name, str(iters),
          n_locals], env=env, capture_output=True, text=True, timeout=900)

@@ -12,7 +12,7 @@ if ! python -c "import jax" 2>/tmp/jax_import_err.$$; then
   echo "" >&2
   echo "FATAL: 'import jax' failed (see traceback above)." >&2
   echo "Install the pinned deps first, e.g.:" >&2
-  echo "    pip install \"jax[cpu]==0.4.37\" \"numpy<2.2\" pytest hypothesis" >&2
+  echo "    pip install \"jax[cpu]==0.9.0\" \"numpy==2.0.2\" pytest hypothesis" >&2
   exit 1
 fi
 rm -f /tmp/jax_import_err.$$

@@ -74,17 +74,6 @@ from repro.kernels.aggregate import get_engine
 from repro.kernels.gcn_spmm import TILE, SplitSpec
 
 
-def _shard_map(f, mesh, in_specs, out_specs):
-    """Version-compat shard_map: `jax.shard_map` (with check_vma) on new
-    JAX, `jax.experimental.shard_map.shard_map` (with check_rep) on 0.4.x."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
 class Topology(NamedTuple):
     """Device-ready padded partition topology (leading axis = partition).
 
@@ -1454,6 +1443,18 @@ class PipeGCN:
 
     # -- SPMD (shard_map) construction ---------------------------------
 
+    def spmd_buffer_specs(self, buffers, axis_name="parts"):
+        """PartitionSpec tree of a buffer dict on a partition mesh: the
+        "es" counters carry the partition axis first (no queue axis); every
+        other buffer carries it after the k-step staleness queue axis when
+        k > 1."""
+        from jax.sharding import PartitionSpec as PS
+
+        pspec = PS(axis_name)
+        bspec = PS(None, axis_name) if self.pipe.staleness_steps > 1 else pspec
+        return {k: jax.tree.map(lambda _: (pspec if k == "es" else bspec), v)
+                for k, v in buffers.items()}
+
     def make_spmd_step(self, mesh, topo: Topology, axis_name="parts",
                        train: bool = True):
         """Build a jitted shard_map step over a 1-D partition mesh axis.
@@ -1518,25 +1519,17 @@ class PipeGCN:
 
         def step(topo_g, params, buffers, data, key, step_idx=None,
                  faults=None):
-            bspec = PS(None, axis_name) if kq > 1 else pspec
-
-            def buf_specs(bufs):
-                # "es" counters carry the partition axis first (no queue
-                # axis), every other buffer follows the k-aware bspec
-                return {k: jax.tree.map(
-                    lambda _: (pspec if k == "es" else bspec), v)
-                    for k, v in bufs.items()}
-
-            f = _shard_map(
-                per_device, mesh=mesh,
+            bspecs = self.spmd_buffer_specs(buffers, axis_name)
+            f = jax.shard_map(
+                per_device, mesh=mesh, check_vma=False,
                 in_specs=(jax.tree.map(lambda _: pspec, tuple(topo_g)),
                           jax.tree.map(lambda _: PS(), params),
-                          buf_specs(buffers),
+                          bspecs,
                           jax.tree.map(lambda _: pspec, tuple(data)),
                           PS(), PS(), PS()),
                 out_specs=(PS(), pspec,
                            jax.tree.map(lambda _: PS(), params) if train else PS(),
-                           buf_specs(buffers) if train else PS()))
+                           bspecs if train else PS()))
             return f(tuple(topo_g), params, buffers, tuple(data), key,
                      step_idx, faults)
 

@@ -113,6 +113,51 @@ def make_spmd_train_step(model: PipeGCN, opt: Optimizer, mesh, topo: Topology,
     return jax.jit(step, donate_argnums=(3,))
 
 
+def place_on_mesh(model: PipeGCN, mesh, axis_name: str, parts, buffers,
+                  replicated):
+    """Put the SPMD step's inputs on `mesh` once, with the shardings its
+    shard_map reads them in: `parts` (a pytree of leading-partition arrays —
+    the topology and data splits) split over `axis_name`, `buffers` by
+    `PipeGCN.spmd_buffer_specs`, and `replicated` (params, optimizer state)
+    copied to every device. The step's outputs keep these shardings, so no
+    step reshards its inputs from one device. An array shared between
+    splits (the data splits share features and labels) is placed once.
+    Returns the placed (parts, buffers, replicated)."""
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as PS
+
+    split = NamedSharding(mesh, PS(axis_name))
+    placed = {}
+
+    def put(x):
+        if id(x) not in placed:
+            placed[id(x)] = jax.device_put(x, split)
+        return placed[id(x)]
+
+    specs = model.spmd_buffer_specs(buffers, axis_name)
+    bufs = jax.device_put(buffers, jax.tree.map(
+        lambda s: NamedSharding(mesh, s), specs,
+        is_leaf=lambda x: isinstance(x, PS)))
+    return (jax.tree.map(put, parts), bufs,
+            jax.device_put(replicated, NamedSharding(mesh, PS())))
+
+
+def make_eval_forward(model: PipeGCN, mesh=None, topo: Topology | None = None,
+                      axis_name: str = "parts"):
+    """Jitted (topo, params, data) -> logits with fresh (synchronous)
+    exchanges, as at test time: the sim-backend forward, or on a mesh the
+    same forward under shard_map, reading the arrays where
+    `place_on_mesh` put them (Pallas kernels cannot be partitioned
+    automatically, so a mesh-placed topology needs the shard_map form)."""
+    if mesh is None:
+        return jax.jit(lambda t, p, d: model.forward(t, p, d)[1])
+    fresh = dataclasses.replace(model, pipe=PipeConfig.vanilla())
+    fwd = fresh.make_spmd_step(mesh, topo, axis_name, train=False)
+    key = jax.random.PRNGKey(0)
+    return jax.jit(
+        lambda t, p, d: fwd(t, p, fresh.init_buffers(t), d, key)[1])
+
+
 def _check_staleness(es, pipe_cfg: PipeConfig, anomalies: dict, epoch: int):
     """Host-side guard bookkeeping on one step's "es" counters; raises
     StalenessExceededError once any exchange's effective staleness
@@ -149,8 +194,9 @@ def train_pipegcn(pipeline, model_cfg: ModelConfig,
     """Reference training loop. With `mesh=None` the step runs on the sim
     backend (single device, partitions vmapped); passing a mesh runs the
     same model under shard_map — partitions need only be a multiple of the
-    mesh size (multi-partition-per-device SPMD). Eval stays on the sim
-    backend either way (global arrays round-trip between backends).
+    mesh size (multi-partition-per-device SPMD). On a mesh the topology,
+    data, buffers and parameters are placed once (`place_on_mesh`) and
+    eval runs the same forward under shard_map (`make_eval_forward`).
 
     Fault tolerance (ISSUE 9):
       * `health` — numerical guard policy; None means HealthConfig()
@@ -313,13 +359,9 @@ def train_pipegcn(pipeline, model_cfg: ModelConfig,
         val_run = elastic_mod.remap_data(pipeline.val_data, plan)
     buffers = model.init_buffers(topo_run)
 
-    def build_step(m, t):
-        return (make_spmd_train_step(model, opt, m, t, axis_name, health=hc)
-                if m is not None
-                else make_jitted_train_step(model, opt, health=hc))
-
-    step = build_step(mesh, topo_run)
-    fwd = jax.jit(lambda t, p, d: model.forward(t, p, d)[1])
+    # the sim backend's step and eval; a mesh replaces both (use_mesh)
+    step = make_jitted_train_step(model, opt, health=hc)
+    fwd = make_eval_forward(model)
 
     def build_tables(active_plan):
         # with a plan active the lost device is already remapped away, so
@@ -385,6 +427,23 @@ def train_pipegcn(pipeline, model_cfg: ModelConfig,
             if log:
                 log(f"resumed from checkpoint step {last} "
                     f"(continuing at epoch {start_epoch})")
+
+    def use_mesh(m):
+        # a mesh gets its own step and eval, and every array the step reads
+        # is placed where its shard_map reads it: once per mesh, not once
+        # per step
+        nonlocal step, fwd, topo_run, train_run, val_run, buffers, params
+        nonlocal opt_state
+        step = make_spmd_train_step(model, opt, m, topo_run, axis_name,
+                                    health=hc)
+        fwd = make_eval_forward(model, m, topo_run, axis_name)
+        (topo_run, train_run, val_run), buffers, (params, opt_state) = (
+            place_on_mesh(model, m, axis_name,
+                          (topo_run, train_run, val_run), buffers,
+                          (params, opt_state)))
+
+    if mesh is not None:
+        use_mesh(mesh)
 
     anomalies = {"skipped_steps": 0, "max_consecutive": 0}
     if pipe_cfg.guard_exchange:
@@ -522,7 +581,7 @@ def train_pipegcn(pipeline, model_cfg: ModelConfig,
                         cur_survivors = tuple(range(orig_devices))
                         cur_n_local = orig_ppd
                         if mesh0 is not None:
-                            step = build_step(mesh0, topo_run)
+                            use_mesh(mesh0)
                         tables = build_tables(None)
                         anomalies["rejoins"] += 1
                         if log:
@@ -572,8 +631,7 @@ def train_pipegcn(pipeline, model_cfg: ModelConfig,
                 cur_n_local = plan.n_local
                 if mesh0 is not None:
                     from repro.launch.mesh import make_survivor_mesh
-                    step = build_step(make_survivor_mesh(plan, axis_name),
-                                      topo_run)
+                    use_mesh(make_survivor_mesh(plan, axis_name))
                 tables = build_tables(plan)
                 recoveries += 1
                 consec = 0

@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.gcn_spmm import resolve_interpret
+
 DEFAULT_Q_BLOCK = 512
 DEFAULT_KV_BLOCK = 512
 NEG_INF = -1e30
@@ -72,7 +74,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 def flash_attention(q, k, v, causal: bool = True, window: int = 0,
                     q_block: int = DEFAULT_Q_BLOCK,
                     kv_block: int = DEFAULT_KV_BLOCK,
-                    interpret: bool = True):
+                    interpret: bool | None = None):
     """q: (B, S, H, d), k/v: (B, T, K, d) with H % K == 0 -> (B, S, H, d)."""
     b, s, h, d = q.shape
     t, kh = k.shape[1], k.shape[2]
@@ -107,7 +109,7 @@ def flash_attention(q, k, v, causal: bool = True, window: int = 0,
             pltpu.VMEM((q_block,), jnp.float32),
             pltpu.VMEM((q_block, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qh.reshape(b, h, nq * q_block, d),
       kh_.reshape(b, kh, nk * kv_block, d),
       vh.reshape(b, kh, nk * kv_block, d))

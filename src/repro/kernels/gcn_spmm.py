@@ -64,11 +64,22 @@ FEAT_BLOCK = 128    # feature columns per grid step
 
 def resolve_interpret(interpret: bool | None) -> bool:
     """`interpret=None` auto-detect shared by every kernel entry point (the
-    jitted ops.py wrappers AND direct callers): interpret on CPU (kernel
-    bodies execute in Python for validation), compiled on real TPU."""
+    jitted ops.py wrappers AND direct callers): compiled on a TPU,
+    interpreted on the CPU (the test platform — kernel bodies execute in
+    Python for validation), and an error on any other backend, where these
+    Mosaic kernels have no lowering and silently interpreting them would
+    hide that the accelerator is not running them."""
     if interpret is not None:
         return interpret
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"the Pallas TPU kernels cannot run on backend {backend!r}: they "
+        "compile for a TPU and are interpreted only on the CPU; use "
+        "agg='coo' there, or pass interpret=True explicitly")
 
 
 def _acc_dtype(dtype) -> jnp.dtype:
@@ -116,7 +127,7 @@ def spmm_block_sparse(tile_rows, tile_cols, tile_vals, h, num_rows: int,
     num_rows: output rows (multiple of T). Rows with no tiles stay zero only
     if every row-block has ≥1 tile — callers pad with an explicit zero tile
     per empty row-block (build_tile_topology does this).
-    interpret=None auto-detects (True on CPU, False on TPU).
+    interpret=None auto-detects (see `resolve_interpret`).
     """
     n_tiles = tile_rows.shape[0]
     f = h.shape[1]
@@ -190,7 +201,7 @@ def spmm_block_sparse_t(t_out, t_in, t_perm, tile_vals, dz, num_cols: int,
     tile_vals: (n_tiles, T, T) forward tile values (NOT transposed).
     dz: (R, F) with R = num_row_blocks·T, F % FEAT_BLOCK == 0.
     num_cols: output rows of the transpose product (multiple of T).
-    interpret=None auto-detects (True on CPU, False on TPU).
+    interpret=None auto-detects (see `resolve_interpret`).
     """
     n_tiles = t_out.shape[0]
     f = dz.shape[1]

@@ -1,7 +1,8 @@
 """Jitted public wrappers for the Pallas kernels.
 
-`interpret` defaults to auto: True on CPU (this container — kernel bodies
-execute in Python for validation), False on real TPU.
+`interpret` defaults to auto (`gcn_spmm.resolve_interpret`): compiled on a
+TPU, interpreted on the CPU (the test platform — kernel bodies execute in
+Python for validation), and an error on any other backend.
 """
 from __future__ import annotations
 
@@ -11,11 +12,6 @@ import jax
 
 from repro.kernels import flash_attention as _fa
 from repro.kernels import gcn_spmm as _spmm
-
-
-# Single source of truth for the auto-detect lives next to the kernels, so
-# direct callers of gcn_spmm.py get the same resolution as these wrappers.
-_auto_interpret = _spmm.resolve_interpret
 
 
 @partial(jax.jit, static_argnames=("num_rows", "interpret"))
@@ -82,7 +78,7 @@ def attention(q, k, v, causal: bool = True, window: int = 0,
     """Flash GQA attention (see flash_attention.py)."""
     return _fa.flash_attention(q, k, v, causal=causal, window=window,
                                q_block=q_block, kv_block=kv_block,
-                               interpret=_auto_interpret(interpret))
+                               interpret=interpret)
 
 
 build_tiles = _spmm.build_tiles

@@ -236,8 +236,6 @@ def dryrun_one(arch_id: str, shape_name: str, multi_pod: bool = False,
     # once — see EXPERIMENTS.md §Dry-run. Roofline compute/memory terms use
     # the analytic model below instead).
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):          # jax<=0.4.x returns [dict]
-        cost = cost[0] if cost else {}
     if cost:
         result["hlo_flops_body_once"] = float(cost.get("flops", 0.0))
         result["hlo_bytes_body_once"] = float(cost.get("bytes accessed", 0.0))

@@ -149,8 +149,6 @@ def dryrun_pipegcn(multi_pod: bool, variant: str = "pipegcn",
             getattr(mem, "argument_size_in_bytes", 0)
             + getattr(mem, "temp_size_in_bytes", 0))
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):          # jax<=0.4.x returns [dict]
-        cost = cost[0] if cost else {}
     if cost:
         result["flops_per_device"] = float(cost.get("flops", 0.0))
         result["bytes_accessed_per_device"] = float(

@@ -9,20 +9,37 @@ Production target: TPU v5e, 256 chips/pod.
 """
 from __future__ import annotations
 
+import os
+
 import jax
+from jax.sharding import AxisType
+
+# The checkout root (src/repro/launch/ -> three levels up).
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
 
 
 def make_mesh(shape, axes, devices=None):
-    """Version-compat mesh construction: `axis_types` (Auto) where the
-    installed JAX supports it (≥0.5), plain `jax.make_mesh` on 0.4.x.
-    `devices` (optional) selects an explicit subset — needed when the mesh
-    is smaller than the platform (multi-partition-per-device runs)."""
-    try:
-        from jax.sharding import AxisType
-        return jax.make_mesh(tuple(shape), tuple(axes), devices=devices,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    except (ImportError, TypeError):
-        return jax.make_mesh(tuple(shape), tuple(axes), devices=devices)
+    """`jax.make_mesh` with Auto axis types. `devices` (optional) selects
+    an explicit subset — needed when the mesh is smaller than the platform
+    (multi-partition-per-device runs)."""
+    return jax.make_mesh(tuple(shape), tuple(axes), devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
+
+
+def configure_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point and
+    return its directory. A `JAX_COMPILATION_CACHE_DIR` set in the
+    environment wins and is left to JAX; otherwise the cache lives at the
+    fixed path `<checkout>/.jax_cache` (git-ignored). The path is part of
+    the cache key, so it never depends on a temporary name, pid or time.
+    Call it from `main()` only — importing a module never enables it."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.join(_CHECKOUT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def make_production_mesh(*, multi_pod: bool = False):

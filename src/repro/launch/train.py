@@ -281,6 +281,8 @@ def main():
                     help="resume bit-exactly from the latest checkpoint "
                          "in --ckpt-dir (gcn workload)")
     args = ap.parse_args()
+    from repro.launch.mesh import configure_compile_cache
+    configure_compile_cache()
     if args.workload == "gcn":
         run_gcn(args)
     else:

@@ -3,6 +3,7 @@ densities, and masking modes (interpret mode on CPU). Sweeps use hypothesis
 when installed, else the deterministic fallback in _hypothesis_compat."""
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from _hypothesis_compat import given, settings, st
 
 from repro.kernels import ops
@@ -127,3 +128,21 @@ def test_ops_wrappers_jit():
     out = ops.attention(q, q, q, causal=True, q_block=128, kv_block=128)
     assert out.shape == q.shape
     assert bool(jnp.all(jnp.isfinite(out)))
+
+
+@pytest.mark.parametrize("backend,want", [("cpu", True), ("tpu", False)])
+def test_resolve_interpret_by_backend(monkeypatch, backend, want):
+    from repro.kernels import gcn_spmm
+    monkeypatch.setattr(gcn_spmm.jax, "default_backend", lambda: backend)
+    assert gcn_spmm.resolve_interpret(None) is want
+    assert gcn_spmm.resolve_interpret(not want) is (not want)
+
+
+def test_resolve_interpret_raises_on_other_backends(monkeypatch):
+    """A GPU, or any backend but the TPU and the CPU, must not silently
+    run the kernels in the Pallas interpreter."""
+    from repro.kernels import gcn_spmm
+    monkeypatch.setattr(gcn_spmm.jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        gcn_spmm.resolve_interpret(None)
+    assert gcn_spmm.resolve_interpret(True) is True
