@@ -69,6 +69,8 @@ import numpy as np
 from repro.core.codec import fused_exchange_encoded, make_codec
 from repro.core.config import ModelConfig, PipeConfig
 from repro.core.faults import BWD, FWD, apply_faults
+from repro.core.trace_utils import (EXCHANGE, LOSS, TRANSFORM, UPDATE,
+                                    layer_scope)
 from repro.graph.halo import PartitionedGraph, extract_partition_tiles
 from repro.kernels.aggregate import get_engine
 from repro.kernels.gcn_spmm import TILE, SplitSpec
@@ -783,6 +785,28 @@ class PipeGCN:
             dcomb = dcomb * drop_mask
         return gw, dcomb[:max_inner], dcomb[max_inner:]
 
+    def _loss(self, backend, logits, data: ShardedData):
+        """The masked loss, normalized over every partition's training
+        nodes, and its gradient dlogits."""
+        mask = data.train_mask.astype(logits.dtype)
+        if self.model.multilabel:
+            count_local = jnp.sum(mask) * self.model.num_classes
+        else:
+            count_local = jnp.sum(mask)
+        total = jnp.maximum(backend.psum_scalar(count_local), 1.0)
+        loss_fn = (_bce_loss_and_grad if self.model.multilabel
+                   else _ce_loss_and_grad)
+        loss_local, dlogits = loss_fn(logits, data.labels, mask, total,
+                                      backend)
+        return backend.psum_scalar(loss_local) / total, dlogits
+
+    @staticmethod
+    def _reduce_grad(backend, ell: int, g):
+        """Layer `ell`'s weight or bias gradient summed over partitions
+        (the only weight-gradient collective of a mesh step)."""
+        with layer_scope("bwd", ell, UPDATE):
+            return backend.psum(g)
+
     # ---------------- forward/backward step (per partition view) --------
 
     def _step_impl(self, backend, topo: Topology, params, buffers, data,
@@ -886,18 +910,18 @@ class PipeGCN:
 
         for ell in range(L):
             fin, fout = dims[ell]
-            if dropout_rate > 0.0:
-                dkey = jax.random.fold_in(key, ell)
-                dm = backend.dropout_mask(
-                    dkey, dropout_rate,
-                    (max_inner + P * topo.slot, fin), P)
-            else:
-                dm = None
-
             act = ell < L - 1
             # Eval never needs residuals: skip the z output (the fused
             # kernel then skips its HBM write) and fuse the ReLU epilogue.
             fuse_relu = act and not train
+            with layer_scope("fwd", ell, TRANSFORM):
+                if dropout_rate > 0.0:
+                    dkey = jax.random.fold_in(key, ell)
+                    dm = backend.dropout_mask(
+                        dkey, dropout_rate,
+                        (max_inner + P * topo.slot, fin), P)
+                else:
+                    dm = None
             if ell in sliced:
                 # Sliced boundary (order forced transform-first): transform
                 # the inner rows FIRST and ship the fout-wide result rows —
@@ -907,69 +931,69 @@ class PipeGCN:
                 # its owner's inner-row mask baked in, instead of the
                 # consumer's halo mask) — identical to the unsliced
                 # schedule at dropout 0.
-                w, b = params[f"w{ell}"], params[f"b{ell}"]
-                w1 = w[:fin] if sage else w
-                h_in = h * dm[..., :max_inner, :] if dm is not None else h
-                hw = h_in @ w1
-                halo = ship_feat(ell, gather(hw, send_idx, send_mask))
-                src = jnp.concatenate([hw, halo], axis=-2)
-                if not lead:
-                    u = self.engine.spmm(tslice, src, max_inner) + b
-                else:
-                    u = jax.vmap(lambda ts, s: self.engine.spmm(
-                        ts, s, max_inner))(tslice, src) + b
-                if sage:
-                    u = u + h_in @ w[fin:]
-                if fuse_relu:
-                    u = jax.nn.relu(u)
+                with layer_scope("fwd", ell, TRANSFORM):
+                    w, b = params[f"w{ell}"], params[f"b{ell}"]
+                    w1 = w[:fin] if sage else w
+                    h_in = h * dm[..., :max_inner, :] if dm is not None else h
+                    hw = h_in @ w1
+                with layer_scope("fwd", ell, EXCHANGE):
+                    halo = ship_feat(ell, gather(hw, send_idx, send_mask))
+                with layer_scope("fwd", ell, TRANSFORM):
+                    src = jnp.concatenate([hw, halo], axis=-2)
+                    if not lead:
+                        u = self.engine.spmm(tslice, src, max_inner) + b
+                    else:
+                        u = jax.vmap(lambda ts, s: self.engine.spmm(
+                            ts, s, max_inner))(tslice, src) + b
+                    if sage:
+                        u = u + h_in @ w[fin:]
+                    if fuse_relu:
+                        u = jax.nn.relu(u)
                 # residual slot 0 holds the masked inner rows (the sliced
                 # backward needs h_in, never the full comb)
                 residuals.append((h_in, None, u, dm))
             else:
-                halo = ship_feat(ell, gather(h, send_idx, send_mask))
-                if not lead:
-                    u, (comb, z) = self._layer_forward(
-                        tslice, params[f"w{ell}"], params[f"b{ell}"], h,
-                        halo, dm, order=orders[ell], fuse_relu=fuse_relu,
-                        with_z=train)
-                else:
-                    fwd = jax.vmap(
-                        lambda ts, h_, halo_, dm_, w_=params[f"w{ell}"],
-                               b_=params[f"b{ell}"], o_=orders[ell]:
-                        self._layer_forward(ts, w_, b_, h_, halo_, dm_,
-                                            order=o_, fuse_relu=fuse_relu,
-                                            with_z=train),
-                        in_axes=(0, 0, 0, 0 if dm is not None else None))
-                    u, (comb, z) = fwd(tslice, h, halo, dm)
+                with layer_scope("fwd", ell, EXCHANGE):
+                    halo = ship_feat(ell, gather(h, send_idx, send_mask))
+                with layer_scope("fwd", ell, TRANSFORM):
+                    if not lead:
+                        u, (comb, z) = self._layer_forward(
+                            tslice, params[f"w{ell}"], params[f"b{ell}"], h,
+                            halo, dm, order=orders[ell], fuse_relu=fuse_relu,
+                            with_z=train)
+                    else:
+                        fwd = jax.vmap(
+                            lambda ts, h_, halo_, dm_, w_=params[f"w{ell}"],
+                                   b_=params[f"b{ell}"], o_=orders[ell]:
+                            self._layer_forward(ts, w_, b_, h_, halo_, dm_,
+                                                order=o_, fuse_relu=fuse_relu,
+                                                with_z=train),
+                            in_axes=(0, 0, 0, 0 if dm is not None else None))
+                        u, (comb, z) = fwd(tslice, h, halo, dm)
                 residuals.append((comb, z, u, dm))
-            h = jax.nn.relu(u) if act and not fuse_relu else u
+            with layer_scope("fwd", ell, TRANSFORM):
+                h = jax.nn.relu(u) if act and not fuse_relu else u
 
         if fuse:
             # ONE collective for all L layers' boundary features, issued
             # after the last layer. Nothing downstream of it is consumed
             # this step (results land in the t+1 buffers), so XLA is free
             # to overlap it with the loss/backward/optimizer compute.
-            for ell, recv in enumerate(
-                    fused_exchange_encoded(backend, pending_feat)):
-                # decode restores the layer's own pre-pack dtype: undoes
-                # the wire encoding AND any promotion from packing layers
-                # of different dtypes into one buffer
-                fresh, vrows = land_feat(ell, recv, feat_dtypes[ell])
-                new_feat[ell] = self._update_buffer_guarded(
-                    buffers["feat"][ell], fresh, pipe.smooth_feat, vrows)
+            with layer_scope("fwd", None, EXCHANGE):
+                for ell, recv in enumerate(
+                        fused_exchange_encoded(backend, pending_feat)):
+                    # decode restores the layer's own pre-pack dtype: undoes
+                    # the wire encoding AND any promotion from packing layers
+                    # of different dtypes into one buffer
+                    fresh, vrows = land_feat(ell, recv, feat_dtypes[ell])
+                    new_feat[ell] = self._update_buffer_guarded(
+                        buffers["feat"][ell], fresh, pipe.smooth_feat, vrows)
 
         logits = h
 
         # -- loss ---------------------------------------------------------
-        mask = data.train_mask.astype(logits.dtype)
-        if self.model.multilabel:
-            count_local = jnp.sum(mask) * self.model.num_classes
-        else:
-            count_local = jnp.sum(mask)
-        total = jnp.maximum(backend.psum_scalar(count_local), 1.0)
-        loss_fn = _bce_loss_and_grad if self.model.multilabel else _ce_loss_and_grad
-        loss_local, dlogits = loss_fn(logits, data.labels, mask, total, backend)
-        loss = backend.psum_scalar(loss_local) / total
+        with jax.named_scope(LOSS):
+            loss, dlogits = self._loss(backend, logits, data)
 
         if not train:
             return loss, logits, None, None
@@ -1030,8 +1054,10 @@ class PipeGCN:
         j = dlogits
         for ell in reversed(range(L)):
             comb, z, u, dm = residuals[ell]
-            du = j if ell == L - 1 else j * (u > 0).astype(j.dtype)
-            grads[f"b{ell}"] = backend.psum(jnp.sum(du, axis=-2))
+            with layer_scope("bwd", ell, TRANSFORM):
+                du = j if ell == L - 1 else j * (u > 0).astype(j.dtype)
+                gb = jnp.sum(du, axis=-2)
+            grads[f"b{ell}"] = self._reduce_grad(backend, ell, gb)
             if ell in sliced:
                 # Sliced backward (transform-first, fout-wide exchange):
                 # ship the PRE-w1 halo rows of dhw = Pᵀ·du back to their
@@ -1041,63 +1067,72 @@ class PipeGCN:
                 # vanilla mode reproduces the unsliced step exactly.
                 fin, fout = dims[ell]
                 w = params[f"w{ell}"]
-                w1 = w[:fin] if sage else w
                 h_in = comb      # residual slot 0 = masked inner rows
-                if not lead:
-                    dhw = self.engine.spmm_t(tslice, du, combined)
-                else:
-                    dhw = jax.vmap(lambda ts, d: self.engine.spmm_t(
-                        ts, d, combined))(tslice, du)
-                db = dhw[..., max_inner:, :]
-                db = db.reshape(db.shape[:-2] + (P, topo.slot, fout))
-                contrib = ship_grad(ell, db, j.dtype)
-                dhw_eff = dhw[..., :max_inner, :] + contrib
-                gw = jnp.swapaxes(h_in, -1, -2) @ dhw_eff
-                if sage:
-                    gw = jnp.concatenate(
-                        [gw, jnp.swapaxes(h_in, -1, -2) @ du], axis=-2)
-                grads[f"w{ell}"] = backend.psum(gw)
-                dh = dhw_eff @ w1.T
-                if sage:
-                    dh = dh + du @ w[fin:].T
-                if dm is not None:
-                    dh = dh * dm[..., :max_inner, :]
+                with layer_scope("bwd", ell, TRANSFORM):
+                    w1 = w[:fin] if sage else w
+                    if not lead:
+                        dhw = self.engine.spmm_t(tslice, du, combined)
+                    else:
+                        dhw = jax.vmap(lambda ts, d: self.engine.spmm_t(
+                            ts, d, combined))(tslice, du)
+                    db = dhw[..., max_inner:, :]
+                    db = db.reshape(db.shape[:-2] + (P, topo.slot, fout))
+                with layer_scope("bwd", ell, EXCHANGE):
+                    contrib = ship_grad(ell, db, j.dtype)
+                with layer_scope("bwd", ell, TRANSFORM):
+                    dhw_eff = dhw[..., :max_inner, :] + contrib
+                    gw = jnp.swapaxes(h_in, -1, -2) @ dhw_eff
+                    if sage:
+                        gw = jnp.concatenate(
+                            [gw, jnp.swapaxes(h_in, -1, -2) @ du], axis=-2)
+                    dh = dhw_eff @ w1.T
+                    if sage:
+                        dh = dh + du @ w[fin:].T
+                    if dm is not None:
+                        dh = dh * dm[..., :max_inner, :]
+                grads[f"w{ell}"] = self._reduce_grad(backend, ell, gw)
                 j = dh           # owner contributions already folded in
                 continue
             need_dcomb = ell > 0    # Alg. 1 stops the backward at layer 0
-            if not lead:
-                gw_local, dh_local, db = self._layer_backward(
-                    tslice, params[f"w{ell}"], du, comb, z, dm, max_inner,
-                    order=orders[ell], need_dcomb=need_dcomb)
-            else:
-                bwd = jax.vmap(
-                    lambda ts, du_, comb_, z_, dm_, w_=params[f"w{ell}"],
-                           o_=orders[ell]:
-                    self._layer_backward(ts, w_, du_, comb_, z_, dm_,
-                                         max_inner, order=o_,
-                                         need_dcomb=need_dcomb),
-                    in_axes=(0, 0, 0, 0 if z is not None else None,
-                             0 if dm is not None else None))
-                gw_local, dh_local, db = bwd(tslice, du, comb, z, dm)
-            grads[f"w{ell}"] = backend.psum(gw_local)
+            with layer_scope("bwd", ell, TRANSFORM):
+                if not lead:
+                    gw_local, dh_local, db = self._layer_backward(
+                        tslice, params[f"w{ell}"], du, comb, z, dm,
+                        max_inner, order=orders[ell], need_dcomb=need_dcomb)
+                else:
+                    bwd = jax.vmap(
+                        lambda ts, du_, comb_, z_, dm_, w_=params[f"w{ell}"],
+                               o_=orders[ell]:
+                        self._layer_backward(ts, w_, du_, comb_, z_, dm_,
+                                             max_inner, order=o_,
+                                             need_dcomb=need_dcomb),
+                        in_axes=(0, 0, 0, 0 if z is not None else None,
+                                 0 if dm is not None else None))
+                    gw_local, dh_local, db = bwd(tslice, du, comb, z, dm)
+            grads[f"w{ell}"] = self._reduce_grad(backend, ell, gw_local)
             if ell == 0:
                 new_grad[ell] = buffers["grad"][ell]
                 break
-            db = db.reshape(db.shape[:-2] + (P, topo.slot, dims[ell][0]))
             # -- boundary gradient communication ---------------------------
-            j = dh_local + ship_grad(ell, db, j.dtype)
+            with layer_scope("bwd", ell, EXCHANGE):
+                db = db.reshape(db.shape[:-2] + (P, topo.slot, dims[ell][0]))
+                contrib = ship_grad(ell, db, j.dtype)
+            with layer_scope("bwd", ell, TRANSFORM):
+                j = dh_local + contrib
 
         if fuse and pending_grad:
             # ONE collective for all L-1 boundary-gradient sends (layer 0
             # sends nothing — Alg. 1 stops its backward at the first layer).
-            recvs = fused_exchange_encoded(backend,
-                                           [w_ for _, w_, _ in pending_grad])
-            for (ell, _, dtype), recv in zip(pending_grad, recvs):
-                # decode restores this layer's pre-pack dtype (see forward)
-                fresh_contrib, vrows = land_grad(ell, recv, dtype)
-                new_grad[ell] = self._update_buffer_guarded(
-                    buffers["grad"][ell], fresh_contrib, pipe.smooth_grad,
-                    vrows)
+            with layer_scope("bwd", None, EXCHANGE):
+                recvs = fused_exchange_encoded(
+                    backend, [w_ for _, w_, _ in pending_grad])
+                for (ell, _, dtype), recv in zip(pending_grad, recvs):
+                    # decode restores this layer's pre-pack dtype (see
+                    # forward)
+                    fresh_contrib, vrows = land_grad(ell, recv, dtype)
+                    new_grad[ell] = self._update_buffer_guarded(
+                        buffers["grad"][ell], fresh_contrib,
+                        pipe.smooth_grad, vrows)
 
         new_buffers = {"feat": tuple(new_feat), "grad": tuple(new_grad)}
         if guard:
@@ -1105,11 +1140,12 @@ class PipeGCN:
             # a valid arrival resets to 0, a fallback increments. Layer 0
             # ships no backward gradient — always "valid". Partition-local
             # bookkeeping: no extra collective enters the step.
-            ones = jnp.ones_like(feat_pv[0])
-            gv = [pv if pv is not None else ones for pv in grad_pv]
-            ok = jnp.stack([jnp.stack(feat_pv, axis=-2),
-                            jnp.stack(gv, axis=-2)], axis=-3)
-            new_buffers["es"] = jnp.where(ok, 0, buffers["es"] + 1)
+            with layer_scope("bwd", None, EXCHANGE):
+                ones = jnp.ones_like(feat_pv[0])
+                gv = [pv if pv is not None else ones for pv in grad_pv]
+                ok = jnp.stack([jnp.stack(feat_pv, axis=-2),
+                                jnp.stack(gv, axis=-2)], axis=-3)
+                new_buffers["es"] = jnp.where(ok, 0, buffers["es"] + 1)
         return loss, logits, grads, new_buffers
 
     # ---------------- split-phase step (ISSUE 6) ----------------
@@ -1211,96 +1247,94 @@ class PipeGCN:
             return self._consume_buffer(buffers["feat"][ell])
 
         def flush_feat():
-            for ell, fresh in enumerate(
-                    fused_exchange_encoded(backend, pending_feat)):
-                fresh = codecs[ell].decode(fresh, dims[ell][0],
-                                           feat_dtypes[ell])
-                fresh = fresh.reshape(
-                    fresh.shape[:-3] + (P * topo.slot, dims[ell][0]))
-                new_feat[ell] = self._update_buffer(
-                    buffers["feat"][ell], fresh, pipe.smooth_feat)
+            with layer_scope("fwd", None, EXCHANGE):
+                for ell, fresh in enumerate(
+                        fused_exchange_encoded(backend, pending_feat)):
+                    fresh = codecs[ell].decode(fresh, dims[ell][0],
+                                               feat_dtypes[ell])
+                    fresh = fresh.reshape(
+                        fresh.shape[:-3] + (P * topo.slot, dims[ell][0]))
+                    new_feat[ell] = self._update_buffer(
+                        buffers["feat"][ell], fresh, pipe.smooth_feat)
 
-        def prep_send(ell, payload):
-            return codecs[ell].encode(payload), payload.dtype
+        def issue_feat(ell, gather_fn, rows):
+            """Gather layer `ell`'s send from `rows`, encode it, and
+            exchange it now (per-layer) or queue it, issuing the packed
+            collective once the last payload is queued (fused). Returns the
+            halo layer `ell` consumes."""
+            with layer_scope("fwd", ell, EXCHANGE):
+                payload = gather_fn(rows, send_idx, send_mask)
+                send, send_dtype = codecs[ell].encode(payload), payload.dtype
+                if not fuse:
+                    return land_feat(ell, send, send_dtype)
+                halo = defer_feat(ell, send, send_dtype)
+            if ell == L - 1:
+                flush_feat()
+            return halo
 
         # -- forward -------------------------------------------------------
         # Layer 0's payload is x itself — available before any compute, so
         # its exchange is issued (or queued) ahead of the loop. For L == 1
         # the fused pack is complete right away and flushes here too.
         h = data.x
-        send, send_dtype = prep_send(0, gather(h, send_idx, send_mask))
-        if fuse:
-            halo = defer_feat(0, send, send_dtype)
-            if L == 1:
-                flush_feat()
-        else:
-            halo = land_feat(0, send, send_dtype)
+        halo = issue_feat(0, gather, h)
 
         for ell in range(L):
             fin, fout = dims[ell]
-            w, b = params[f"w{ell}"], params[f"b{ell}"]
-            w1 = w[:fin] if sage else w
-            if dropout_rate > 0.0:
-                dkey = jax.random.fold_in(key, ell)
-                dm = backend.dropout_mask(
-                    dkey, dropout_rate, (combined, fin), P)
-            else:
-                dm = None
-            comb = jnp.concatenate([h, halo], axis=-2)
-            if dm is not None:
-                comb = comb * dm
             order = orders[ell]
-            src = comb @ w1 if order == "transform-first" else comb
             act = ell < L - 1
+            with layer_scope("fwd", ell, TRANSFORM):
+                w, b = params[f"w{ell}"], params[f"b{ell}"]
+                w1 = w[:fin] if sage else w
+                if dropout_rate > 0.0:
+                    dkey = jax.random.fold_in(key, ell)
+                    dm = backend.dropout_mask(
+                        dkey, dropout_rate, (combined, fin), P)
+                else:
+                    dm = None
+                comb = jnp.concatenate([h, halo], axis=-2)
+                if dm is not None:
+                    comb = comb * dm
+                src = comb @ w1 if order == "transform-first" else comb
 
-            # boundary phase: only rows [rt, max_inner) of raw_b are valid.
-            raw_b = spmm_phase(src, "boundary")
-            tail_b = raw_b[..., rt:, :]
-            u_bt = tail_b + b if order == "transform-first" else tail_b @ w1 + b
-            if sage:
-                u_bt = u_bt + comb[..., rt:max_inner, :] @ w[fin:]
-            h_bt = jax.nn.relu(u_bt) if act else u_bt
+                # boundary phase: only rows [rt, max_inner) of raw_b are
+                # valid.
+                raw_b = spmm_phase(src, "boundary")
+                tail_b = raw_b[..., rt:, :]
+                u_bt = (tail_b + b if order == "transform-first"
+                        else tail_b @ w1 + b)
+                if sage:
+                    u_bt = u_bt + comb[..., rt:max_inner, :] @ w[fin:]
+                h_bt = jax.nn.relu(u_bt) if act else u_bt
 
             # issue the NEXT layer's exchange between the phases: its
             # payload rows all live in the tail just produced.
             if ell + 1 < L:
-                send, send_dtype = prep_send(
-                    ell + 1, gather_tail(h_bt, send_idx, send_mask))
-                if fuse:
-                    halo = defer_feat(ell + 1, send, send_dtype)
-                    if ell + 1 == L - 1:
-                        flush_feat()   # last payload queued -> issue now
-                else:
-                    halo = land_feat(ell + 1, send, send_dtype)
+                halo = issue_feat(ell + 1, gather_tail, h_bt)
 
             # interior phase overlaps the in-flight collective.
-            raw_i = spmm_phase(src, "interior")
-            head_i = raw_i[..., :rt, :]
-            if order == "transform-first":
-                u_ih = head_i + b
-                z = None
-            else:
-                u_ih = head_i @ w1 + b
-                z = (jnp.concatenate([head_i, tail_b], axis=-2)
-                     if train else None)
-            if sage:
-                u_ih = u_ih + comb[..., :rt, :] @ w[fin:]
-            u = jnp.concatenate([u_ih, u_bt], axis=-2)
+            with layer_scope("fwd", ell, TRANSFORM):
+                raw_i = spmm_phase(src, "interior")
+                head_i = raw_i[..., :rt, :]
+                if order == "transform-first":
+                    u_ih = head_i + b
+                    z = None
+                else:
+                    u_ih = head_i @ w1 + b
+                    z = (jnp.concatenate([head_i, tail_b], axis=-2)
+                         if train else None)
+                if sage:
+                    u_ih = u_ih + comb[..., :rt, :] @ w[fin:]
+                u = jnp.concatenate([u_ih, u_bt], axis=-2)
+                h = (jnp.concatenate([jax.nn.relu(u_ih), h_bt], axis=-2)
+                     if act else u)
             residuals.append((comb, z, u, dm))
-            h = jnp.concatenate([jax.nn.relu(u_ih), h_bt], axis=-2) if act else u
 
         logits = h
 
         # -- loss ---------------------------------------------------------
-        mask = data.train_mask.astype(logits.dtype)
-        if self.model.multilabel:
-            count_local = jnp.sum(mask) * self.model.num_classes
-        else:
-            count_local = jnp.sum(mask)
-        total = jnp.maximum(backend.psum_scalar(count_local), 1.0)
-        loss_fn = _bce_loss_and_grad if self.model.multilabel else _ce_loss_and_grad
-        loss_local, dlogits = loss_fn(logits, data.labels, mask, total, backend)
-        loss = backend.psum_scalar(loss_local) / total
+        with jax.named_scope(LOSS):
+            loss, dlogits = self._loss(backend, logits, data)
 
         if not train:
             return loss, logits, None, None
@@ -1315,104 +1349,115 @@ class PipeGCN:
         pending_grad = []
 
         def flush_grad():
-            recvs = fused_exchange_encoded(backend,
-                                           [d for _, d, _ in pending_grad])
-            for (ell, _, db_dtype), db_recv in zip(pending_grad, recvs):
-                db_recv = codecs[ell].decode(db_recv, dims[ell][0], db_dtype)
-                fresh_contrib = scatter(db_recv, send_idx, send_mask)
-                new_grad[ell] = self._update_buffer(
-                    buffers["grad"][ell], fresh_contrib, pipe.smooth_grad)
+            with layer_scope("bwd", None, EXCHANGE):
+                recvs = fused_exchange_encoded(
+                    backend, [d for _, d, _ in pending_grad])
+                for (ell, _, db_dtype), db_recv in zip(pending_grad, recvs):
+                    db_recv = codecs[ell].decode(db_recv, dims[ell][0],
+                                                 db_dtype)
+                    fresh_contrib = scatter(db_recv, send_idx, send_mask)
+                    new_grad[ell] = self._update_buffer(
+                        buffers["grad"][ell], fresh_contrib, pipe.smooth_grad)
 
         j = dlogits
         for ell in reversed(range(L)):
             comb, z, u, dm = residuals[ell]
             fin, _ = dims[ell]
             w = params[f"w{ell}"]
-            w1 = w[:fin] if sage else w
-            du = j if ell == L - 1 else j * (u > 0).astype(j.dtype)
-            grads[f"b{ell}"] = backend.psum(jnp.sum(du, axis=-2))
+            with layer_scope("bwd", ell, TRANSFORM):
+                w1 = w[:fin] if sage else w
+                du = j if ell == L - 1 else j * (u > 0).astype(j.dtype)
+                gb = jnp.sum(du, axis=-2)
+            grads[f"b{ell}"] = self._reduce_grad(backend, ell, gb)
             if ell == 0:
                 # Alg. 1 stops the backward at layer 0: weight grad only,
                 # no Pᵀ pass under aggregate-first — reuse the unsplit
                 # per-layer backward (need_dcomb=False).
-                if not lead:
-                    gw_local, _, _ = self._layer_backward(
-                        tslice, w, du, comb, z, dm, max_inner,
-                        order=orders[0], need_dcomb=False)
-                else:
-                    bwd = jax.vmap(
-                        lambda ts, du_, comb_, z_, dm_, w_=w:
-                        self._layer_backward(ts, w_, du_, comb_, z_, dm_,
-                                             max_inner, order=orders[0],
-                                             need_dcomb=False),
-                        in_axes=(0, 0, 0, 0 if z is not None else None,
-                                 0 if dm is not None else None))
-                    gw_local, _, _ = bwd(tslice, du, comb, z, dm)
-                grads[f"w{ell}"] = backend.psum(gw_local)
+                with layer_scope("bwd", ell, TRANSFORM):
+                    if not lead:
+                        gw_local, _, _ = self._layer_backward(
+                            tslice, w, du, comb, z, dm, max_inner,
+                            order=orders[0], need_dcomb=False)
+                    else:
+                        bwd = jax.vmap(
+                            lambda ts, du_, comb_, z_, dm_, w_=w:
+                            self._layer_backward(ts, w_, du_, comb_, z_, dm_,
+                                                 max_inner, order=orders[0],
+                                                 need_dcomb=False),
+                            in_axes=(0, 0, 0, 0 if z is not None else None,
+                                     0 if dm is not None else None))
+                        gw_local, _, _ = bwd(tslice, du, comb, z, dm)
+                grads[f"w{ell}"] = self._reduce_grad(backend, ell, gw_local)
                 new_grad[0] = buffers["grad"][0]
                 break
 
             order = orders[ell]
-            # ONE dense op ahead of both phases under aggregate-first
-            # (δhw = du·w1ᵀ); transform-first transposes du raw and applies
-            # w1ᵀ per phase (the pre-w1 pieces also feed the weight grad).
-            src_t = du if order == "transform-first" else du @ w1.T
-            if sage:
-                sage_t = du @ w[fin:].T
+            with layer_scope("bwd", ell, TRANSFORM):
+                # ONE dense op ahead of both phases under aggregate-first
+                # (δhw = du·w1ᵀ); transform-first transposes du raw and
+                # applies w1ᵀ per phase (the pre-w1 pieces also feed the
+                # weight grad).
+                src_t = du if order == "transform-first" else du @ w1.T
+                if sage:
+                    sage_t = du @ w[fin:].T
 
-            # boundary phase: comb rows [ct, combined) valid.
-            raw_tb = spmm_t_phase(src_t, "boundary")
-            dhw_b = raw_tb[..., ct:, :]
-            d_bt = dhw_b @ w1.T if order == "transform-first" else dhw_b
-            if sage:
-                d_bt = d_bt.at[..., :max_inner - ct, :].add(
-                    sage_t[..., ct:, :])
-            if dm is not None:
-                d_bt = d_bt * dm[..., ct:, :]
+                # boundary phase: comb rows [ct, combined) valid.
+                raw_tb = spmm_t_phase(src_t, "boundary")
+                dhw_b = raw_tb[..., ct:, :]
+                d_bt = dhw_b @ w1.T if order == "transform-first" else dhw_b
+                if sage:
+                    d_bt = d_bt.at[..., :max_inner - ct, :].add(
+                        sage_t[..., ct:, :])
+                if dm is not None:
+                    d_bt = d_bt * dm[..., ct:, :]
 
             # gradient send = the halo rows of the boundary phase; issue
             # the exchange before the interior phase runs.
-            db = d_bt[..., max_inner - ct:, :]
-            db = db.reshape(db.shape[:-2] + (P, topo.slot, fin))
-            db_dtype = db.dtype if codecs[ell].name == "f32" else j.dtype
-            wire = codecs[ell].encode(db)
-            if fuse:
-                pending_grad.append((ell, wire, db_dtype))
-                contrib = self._consume_buffer(buffers["grad"][ell])
-                if ell == 1:
-                    flush_grad()   # last backward payload -> issue now
-            else:
-                db_recv = codecs[ell].decode(backend.exchange(wire), fin,
-                                             db_dtype)
-                fresh_contrib = scatter(db_recv, send_idx, send_mask)
-                if pipe.stale:
+            with layer_scope("bwd", ell, EXCHANGE):
+                db = d_bt[..., max_inner - ct:, :]
+                db = db.reshape(db.shape[:-2] + (P, topo.slot, fin))
+                db_dtype = db.dtype if codecs[ell].name == "f32" else j.dtype
+                wire = codecs[ell].encode(db)
+                if fuse:
+                    pending_grad.append((ell, wire, db_dtype))
                     contrib = self._consume_buffer(buffers["grad"][ell])
-                    new_grad[ell] = self._update_buffer(
-                        buffers["grad"][ell], fresh_contrib, pipe.smooth_grad)
                 else:
-                    contrib = fresh_contrib
-                    new_grad[ell] = buffers["grad"][ell]
+                    db_recv = codecs[ell].decode(backend.exchange(wire), fin,
+                                                 db_dtype)
+                    fresh_contrib = scatter(db_recv, send_idx, send_mask)
+                    if pipe.stale:
+                        contrib = self._consume_buffer(buffers["grad"][ell])
+                        new_grad[ell] = self._update_buffer(
+                            buffers["grad"][ell], fresh_contrib,
+                            pipe.smooth_grad)
+                    else:
+                        contrib = fresh_contrib
+                        new_grad[ell] = buffers["grad"][ell]
+            if fuse and ell == 1:
+                flush_grad()   # last backward payload -> issue now
 
             # interior phase overlaps the in-flight gradient exchange.
-            raw_ti = spmm_t_phase(src_t, "interior")
-            dhw_i = raw_ti[..., :ct, :]
-            if order == "transform-first":
-                d_ih = dhw_i @ w1.T
-                dhw_full = jnp.concatenate([dhw_i, dhw_b], axis=-2)
-                gw = jnp.swapaxes(comb, -1, -2) @ dhw_full
-            else:
-                d_ih = dhw_i
-                gw = jnp.swapaxes(z, -1, -2) @ du
-            if sage:
-                gw = jnp.concatenate(
-                    [gw, jnp.swapaxes(comb[..., :max_inner, :], -1, -2) @ du],
-                    axis=-2)
-                d_ih = d_ih + sage_t[..., :ct, :]
-            if dm is not None:
-                d_ih = d_ih * dm[..., :ct, :]
-            grads[f"w{ell}"] = backend.psum(gw)
-            j = jnp.concatenate(
-                [d_ih, d_bt[..., :max_inner - ct, :]], axis=-2) + contrib
+            with layer_scope("bwd", ell, TRANSFORM):
+                raw_ti = spmm_t_phase(src_t, "interior")
+                dhw_i = raw_ti[..., :ct, :]
+                if order == "transform-first":
+                    d_ih = dhw_i @ w1.T
+                    dhw_full = jnp.concatenate([dhw_i, dhw_b], axis=-2)
+                    gw = jnp.swapaxes(comb, -1, -2) @ dhw_full
+                else:
+                    d_ih = dhw_i
+                    gw = jnp.swapaxes(z, -1, -2) @ du
+                if sage:
+                    gw = jnp.concatenate(
+                        [gw,
+                         jnp.swapaxes(comb[..., :max_inner, :], -1, -2) @ du],
+                        axis=-2)
+                    d_ih = d_ih + sage_t[..., :ct, :]
+                if dm is not None:
+                    d_ih = d_ih * dm[..., :ct, :]
+                j = jnp.concatenate(
+                    [d_ih, d_bt[..., :max_inner - ct, :]], axis=-2) + contrib
+            grads[f"w{ell}"] = self._reduce_grad(backend, ell, gw)
 
         new_buffers = {"feat": tuple(new_feat), "grad": tuple(new_grad)}
         return loss, logits, grads, new_buffers

@@ -1,8 +1,38 @@
-"""Jaxpr-level collective accounting for the PipeGCN step.
+"""The PipeGCN step's trace vocabulary, and jaxpr-level collective
+accounting.
 
-The fused deferred exchange collapses the per-step boundary collectives from
-2L-1 blocking per-layer calls (L forward feature exchanges + L-1 backward
-gradient exchanges) to exactly 2 (one packed exchange per direction). These
+Scopes and spans. Every op of the train step and the eval forward is
+emitted under a `jax.named_scope` path, which XLA keeps as the op's
+`op_name` metadata (no cost at run time): `fwd/L{i}` or `bwd/L{i}` names
+the layer, then one of `SCOPES` names the part of the step:
+
+  aggregate  the engines' SpMM P.H and its transpose (a fused kernel's
+             dense epilogue included)
+  transform  the dense layer math outside the engines: H.W, the SAGE self
+             term, the weight and input gradients, activations, dropout
+  exchange   payload to halo: the send gather, the codec's encode and
+             decode, the exchange itself (a transpose on the sim backend,
+             `all_to_all` on a mesh), the receive scatter, the stale-buffer
+             update
+  loss       the masked loss and its gradient
+  update     the weight-gradient reduction, the health check and its
+             rollback, the optimizer
+
+Scopes nest (the engines open `aggregate` inside a layer's `transform`);
+the innermost of `SCOPES` on an op's path is its part. The fused deferred
+exchange packs every layer's payload into one collective, so its ops carry
+`fwd/exchange` or `bwd/exchange` with no layer.
+
+The epoch loop of `repro.core.trainer.train_pipegcn` opens host spans
+(`jax.profiler.TraceAnnotation`) named by `HOST_SPANS`: `trainer/epoch`
+(a `StepTraceAnnotation` carrying `step_num`) around each iteration, and
+its children around what the host does in it. The profiler records them
+on the clock of the device ops.
+
+Collective accounting. The fused deferred exchange collapses the per-step
+boundary collectives from 2L-1 blocking per-layer calls (L forward feature
+exchanges + L-1 backward gradient exchanges) to exactly 2 (one packed
+exchange per direction). These
 helpers trace a step function and count primitives in the jaxpr — the
 regression test and the benchmark trajectory both pin the counts so the
 fusion can never silently regress.
@@ -14,6 +44,23 @@ axis is still one `all_to_all` eqn in the trace.
 from __future__ import annotations
 
 import jax
+
+SCOPES = ("aggregate", "transform", "exchange", "loss", "update")
+AGGREGATE, TRANSFORM, EXCHANGE, LOSS, UPDATE = SCOPES
+# The epoch span, then its children: the PRNG split, the step's dispatch,
+# host reads that block on the device, the guard's host checks, the eval,
+# the log callback, checkpoint writes, and elastic recovery.
+HOST_SPANS = ("trainer/epoch", "trainer/rng", "trainer/dispatch",
+              "trainer/wait", "trainer/guard", "trainer/eval", "trainer/log",
+              "trainer/checkpoint", "trainer/recover")
+
+
+def layer_scope(direction: str, ell: int | None, part: str):
+    """`jax.named_scope` of one part of one layer's forward or backward:
+    `layer_scope("fwd", 2, EXCHANGE)` is `fwd/L2/exchange`; `ell=None`
+    names a part shared by every layer (`fwd/exchange`)."""
+    layer = () if ell is None else (f"L{ell}",)
+    return jax.named_scope("/".join((direction,) + layer + (part,)))
 
 
 def _iter_subjaxprs(v):

@@ -14,6 +14,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.core import elastic as elastic_mod
 from repro.core.config import ModelConfig, PipeConfig
@@ -22,6 +23,7 @@ from repro.core.faults import FaultPlan, StalenessExceededError
 from repro.core.health import (HealthConfig, TrainingAnomalyError,
                                health_check, tree_select)
 from repro.core.pipegcn import PipeGCN, Topology
+from repro.core.trace_utils import UPDATE
 from repro.optim import Optimizer, adam
 
 
@@ -38,7 +40,7 @@ class TrainResult:
     the run exited early on a SIGTERM/SIGINT (`preempted`, after writing
     a final checkpoint)."""
 
-    history: dict          # lists: loss, val_acc, test_acc, epoch_time
+    history: dict          # lists: loss, val_acc, test_acc, epoch (per eval)
     params: dict
     final_metrics: dict
     epochs_per_sec: float
@@ -63,25 +65,35 @@ def make_jitted_train_step(model: PipeGCN, opt: Optimizer,
     ``{"ok", "grad_norm"}`` report. `step_idx` + `faults` (compiled
     FaultTables) inject that step's exchange faults; both default to None
     which traces the historical fault-free step."""
-    guarded = health is not None and health.enabled
-    limit = health.grad_norm_limit if guarded else None
+    health = health if health is not None and health.enabled else None
 
     def step(topo, params, opt_state, buffers, data, key, step_idx=None,
              faults=None):
         loss, grads, new_buffers, _ = model.train_step(
             topo, params, buffers, data, key, step_idx=step_idx,
             faults=faults)
+        return _update(opt, health, loss, grads, params, opt_state, buffers,
+                       new_buffers)
+
+    return jax.jit(step, donate_argnums=(3,))
+
+
+def _update(opt: Optimizer, health: HealthConfig | None, loss, grads, params,
+            opt_state, buffers, new_buffers):
+    """The train step's `update` scope: the optimizer and, with an enabled
+    `health`, the health check and the rollback to the previous state.
+    Returns the step's outputs."""
+    with jax.named_scope(UPDATE):
         new_params, new_opt_state = opt.apply(params, grads, opt_state)
-        if not guarded:
+        if health is None:
             return loss, new_params, new_opt_state, new_buffers
-        rep = health_check(loss, grads, new_buffers, grad_norm_limit=limit)
+        rep = health_check(loss, grads, new_buffers,
+                           grad_norm_limit=health.grad_norm_limit)
         ok = rep["ok"]
         new_params = tree_select(ok, new_params, params)
         new_opt_state = tree_select(ok, new_opt_state, opt_state)
         new_buffers = tree_select(ok, new_buffers, buffers)
         return loss, new_params, new_opt_state, new_buffers, rep
-
-    return jax.jit(step, donate_argnums=(3,))
 
 
 def make_spmd_train_step(model: PipeGCN, opt: Optimizer, mesh, topo: Topology,
@@ -93,22 +105,14 @@ def make_spmd_train_step(model: PipeGCN, opt: Optimizer, mesh, topo: Topology,
     replicated grads. Same signature/returns as the sim-backend step
     (health rollback and fault injection included)."""
     spmd_step = model.make_spmd_step(mesh, topo, axis_name, train=True)
-    guarded = health is not None and health.enabled
-    limit = health.grad_norm_limit if guarded else None
+    health = health if health is not None and health.enabled else None
 
     def step(topo, params, opt_state, buffers, data, key, step_idx=None,
              faults=None):
         loss, _, grads, new_buffers = spmd_step(topo, params, buffers, data,
                                                 key, step_idx, faults)
-        new_params, new_opt_state = opt.apply(params, grads, opt_state)
-        if not guarded:
-            return loss, new_params, new_opt_state, new_buffers
-        rep = health_check(loss, grads, new_buffers, grad_norm_limit=limit)
-        ok = rep["ok"]
-        new_params = tree_select(ok, new_params, params)
-        new_opt_state = tree_select(ok, new_opt_state, opt_state)
-        new_buffers = tree_select(ok, new_buffers, buffers)
-        return loss, new_params, new_opt_state, new_buffers, rep
+        return _update(opt, health, loss, grads, params, opt_state, buffers,
+                       new_buffers)
 
     return jax.jit(step, donate_argnums=(3,))
 
@@ -149,13 +153,18 @@ def make_eval_forward(model: PipeGCN, mesh=None, topo: Topology | None = None,
     same forward under shard_map, reading the arrays where
     `place_on_mesh` put them (Pallas kernels cannot be partitioned
     automatically, so a mesh-placed topology needs the shard_map form)."""
+    # a named function, so that a trace names the program jit_eval_forward
     if mesh is None:
-        return jax.jit(lambda t, p, d: model.forward(t, p, d)[1])
+        def eval_forward(t, p, d):
+            return model.forward(t, p, d)[1]
+        return jax.jit(eval_forward)
     fresh = dataclasses.replace(model, pipe=PipeConfig.vanilla())
     fwd = fresh.make_spmd_step(mesh, topo, axis_name, train=False)
     key = jax.random.PRNGKey(0)
-    return jax.jit(
-        lambda t, p, d: fwd(t, p, fresh.init_buffers(t), d, key)[1])
+
+    def eval_forward(t, p, d):
+        return fwd(t, p, fresh.init_buffers(t), d, key)[1]
+    return jax.jit(eval_forward)
 
 
 def _check_staleness(es, pipe_cfg: PipeConfig, anomalies: dict, epoch: int):
@@ -491,160 +500,200 @@ def train_pipegcn(pipeline, model_cfg: ModelConfig,
     cur_n_local = plan.n_local if plan is not None else orig_ppd
     last_metric, last_metric_epoch = None, -1
     history = {"loss": [], "val_acc": [], "test_acc": [], "epoch": []}
+    finished = 0    # epochs that ran to their end, re-runs after recovery too
     t0 = time.perf_counter()
     epoch = start_epoch
     try:
         while epoch < epochs:
-            try:
-                key, sub = jax.random.split(key)
-                if tables is not None:
-                    out = step(topo_run, params, opt_state, buffers,
-                               train_run, sub,
-                               jnp.asarray(epoch, jnp.int32), tables)
-                else:
-                    out = step(topo_run, params, opt_state, buffers,
-                               train_run, sub)
-                if hc is not None:
-                    loss, params, opt_state, buffers, rep = out
-                    if not bool(rep["ok"]):
-                        anomalies["skipped_steps"] += 1
-                        consec += 1
-                        anomalies["max_consecutive"] = max(
-                            anomalies["max_consecutive"], consec)
-                        if consec >= hc.max_consecutive_anomalies:
-                            raise TrainingAnomalyError(
-                                f"{consec} consecutive unhealthy training "
-                                f"steps (epoch {epoch}, loss {float(loss)}, "
-                                f"grad norm {float(rep['grad_norm'])}); "
-                                "aborting instead of spinning on a "
-                                "poisoned run")
+            # host spans (repro.core.trace_utils.HOST_SPANS): what the host
+            # does in each epoch, on the profiler's clock when it traces
+            with StepTraceAnnotation("trainer/epoch", step_num=epoch):
+                try:
+                    with TraceAnnotation("trainer/rng"):
+                        key, sub = jax.random.split(key)
+                    with TraceAnnotation("trainer/dispatch"):
+                        if tables is not None:
+                            out = step(topo_run, params, opt_state, buffers,
+                                       train_run, sub,
+                                       jnp.asarray(epoch, jnp.int32), tables)
+                        else:
+                            out = step(topo_run, params, opt_state, buffers,
+                                       train_run, sub)
+                    if hc is not None:
+                        loss, params, opt_state, buffers, rep = out
+                        with TraceAnnotation("trainer/wait"):
+                            ok = bool(rep["ok"])
+                        with TraceAnnotation("trainer/guard"):
+                            if not ok:
+                                anomalies["skipped_steps"] += 1
+                                consec += 1
+                                anomalies["max_consecutive"] = max(
+                                    anomalies["max_consecutive"], consec)
+                                if consec >= hc.max_consecutive_anomalies:
+                                    raise TrainingAnomalyError(
+                                        f"{consec} consecutive unhealthy "
+                                        f"training steps (epoch {epoch}, "
+                                        f"loss {float(loss)}, grad norm "
+                                        f"{float(rep['grad_norm'])}); "
+                                        "aborting instead of spinning on a "
+                                        "poisoned run")
+                            else:
+                                consec = 0
                     else:
-                        consec = 0
-                else:
-                    loss, params, opt_state, buffers = out
-                if pipe_cfg.guard_exchange:
-                    es_host = np.asarray(buffers["es"])
-                    if el_on:
-                        # device loss pre-empts the staleness abort: a
-                        # blanket whole-device fallback row is an outage
-                        # to recover from, not a contract violation
-                        down = elastic_mod.detect_device_loss(
-                            es_host, cur_n_local, P, elastic.detect_after)
-                        if down is not None:
-                            dev = (cur_survivors[down] if plan is not None
-                                   else down)
-                            rest = tuple(s for s in cur_survivors
-                                         if s != dev)
-                            raise elastic_mod.DeviceLossError(
-                                f"device {dev} detected down at epoch "
-                                f"{epoch}: every forward exchange out of "
-                                f"it has fallen back >= "
-                                f"{elastic.detect_after} consecutive steps",
-                                dev, rest, epoch)
-                    _check_staleness(es_host, pipe_cfg, anomalies, epoch)
-                if epoch % eval_every == 0 or epoch == epochs - 1:
-                    logits = fwd(topo_run, params, val_run)
-                    m = pipeline.metric(logits)
-                    last_metric, last_metric_epoch = m, epoch
-                    history["loss"].append(float(loss))
-                    history["val_acc"].append(m["val"])
-                    history["test_acc"].append(m["test"])
-                    history["epoch"].append(epoch)
-                    if log:
-                        line = (f"epoch {epoch:5d} loss {float(loss):.4f} "
-                                f"val {m['val']:.4f} test {m['test']:.4f}")
-                        if anomalies["skipped_steps"]:
-                            line += f" anomalies {anomalies['skipped_steps']}"
-                        if (pipe_cfg.guard_exchange
-                                and anomalies["exchange_fallbacks"]):
-                            line += (
-                                f" fallbacks {anomalies['exchange_fallbacks']}"
-                                f" es {anomalies['max_effective_staleness']}"
-                                f"/{pipe_cfg.max_staleness}")
-                        log(line)
-                saved = False
-                if (ckpt_dir and checkpoint_every
-                        and (epoch + 1) % checkpoint_every == 0):
-                    flat = save_state(epoch + 1)
-                    saved = True
-                    if (plan is not None and el_on and elastic.rejoin
-                            and device_back(epoch + 1)):
-                        # rejoin: the just-saved flat state IS the live
-                        # state unmapped — resume it on the full device
-                        # count, warm-marking the partitions moving home
-                        moved = plan.moved_partitions()
-                        buffers = elastic_mod.warm_mark(
-                            flat, moved, elastic.warm_staleness, P)
-                        topo_run, train_run, val_run = (
-                            topo, pipeline.train_data, pipeline.val_data)
-                        plan = None
-                        cur_survivors = tuple(range(orig_devices))
-                        cur_n_local = orig_ppd
-                        if mesh0 is not None:
-                            use_mesh(mesh0)
-                        tables = build_tables(None)
-                        anomalies["rejoins"] += 1
+                        loss, params, opt_state, buffers = out
+                    if pipe_cfg.guard_exchange:
+                        with TraceAnnotation("trainer/wait"):
+                            es_host = np.asarray(buffers["es"])
+                        with TraceAnnotation("trainer/guard"):
+                            if el_on:
+                                # device loss pre-empts the staleness abort:
+                                # a blanket whole-device fallback row is an
+                                # outage to recover from, not a contract
+                                # violation
+                                down = elastic_mod.detect_device_loss(
+                                    es_host, cur_n_local, P,
+                                    elastic.detect_after)
+                                if down is not None:
+                                    dev = (cur_survivors[down]
+                                           if plan is not None else down)
+                                    rest = tuple(s for s in cur_survivors
+                                                 if s != dev)
+                                    raise elastic_mod.DeviceLossError(
+                                        f"device {dev} detected down at "
+                                        f"epoch {epoch}: every forward "
+                                        "exchange out of it has fallen back "
+                                        f">= {elastic.detect_after} "
+                                        "consecutive steps", dev, rest, epoch)
+                            _check_staleness(es_host, pipe_cfg, anomalies,
+                                             epoch)
+                    if epoch % eval_every == 0 or epoch == epochs - 1:
+                        with TraceAnnotation("trainer/eval"):
+                            logits = fwd(topo_run, params, val_run)
+                            m = pipeline.metric(logits)
+                            last_metric, last_metric_epoch = m, epoch
+                            history["loss"].append(float(loss))
+                            history["val_acc"].append(m["val"])
+                            history["test_acc"].append(m["test"])
+                            history["epoch"].append(epoch)
                         if log:
-                            log(f"rejoin: scaled back up to {orig_devices} "
-                                f"devices at checkpoint step {epoch + 1} "
-                                f"({len(moved)} partitions warm-marked)")
-                if stop_signals:
-                    if ckpt_dir and checkpoint_every and not saved:
-                        save_state(epoch + 1)
-                    preempted = True
+                            with TraceAnnotation("trainer/log"):
+                                line = (f"epoch {epoch:5d} loss "
+                                        f"{float(loss):.4f} val "
+                                        f"{m['val']:.4f} test {m['test']:.4f}")
+                                if anomalies["skipped_steps"]:
+                                    line += (" anomalies "
+                                             f"{anomalies['skipped_steps']}")
+                                if (pipe_cfg.guard_exchange
+                                        and anomalies["exchange_fallbacks"]):
+                                    fb = anomalies["exchange_fallbacks"]
+                                    es = anomalies["max_effective_staleness"]
+                                    line += (f" fallbacks {fb} es {es}"
+                                             f"/{pipe_cfg.max_staleness}")
+                                log(line)
+                    saved = False
+                    if (ckpt_dir and checkpoint_every
+                            and (epoch + 1) % checkpoint_every == 0):
+                        with TraceAnnotation("trainer/checkpoint"):
+                            flat = save_state(epoch + 1)
+                        saved = True
+                        if (plan is not None and el_on and elastic.rejoin
+                                and device_back(epoch + 1)):
+                            with TraceAnnotation("trainer/recover"):
+                                # rejoin: the just-saved flat state IS the
+                                # live state unmapped — resume it on the full
+                                # device count, warm-marking the partitions
+                                # moving home
+                                moved = plan.moved_partitions()
+                                buffers = elastic_mod.warm_mark(
+                                    flat, moved, elastic.warm_staleness, P)
+                                topo_run, train_run, val_run = (
+                                    topo, pipeline.train_data,
+                                    pipeline.val_data)
+                                plan = None
+                                cur_survivors = tuple(range(orig_devices))
+                                cur_n_local = orig_ppd
+                                if mesh0 is not None:
+                                    use_mesh(mesh0)
+                                tables = build_tables(None)
+                                anomalies["rejoins"] += 1
+                            if log:
+                                with TraceAnnotation("trainer/log"):
+                                    log(f"rejoin: scaled back up to "
+                                        f"{orig_devices} devices at "
+                                        f"checkpoint step {epoch + 1} "
+                                        f"({len(moved)} partitions "
+                                        "warm-marked)")
+                    finished += 1
+                    if stop_signals:
+                        if ckpt_dir and checkpoint_every and not saved:
+                            with TraceAnnotation("trainer/checkpoint"):
+                                save_state(epoch + 1)
+                        preempted = True
+                        if log:
+                            with TraceAnnotation("trainer/log"):
+                                log(f"preempted (signal "
+                                    f"{int(stop_signals[0])}): epoch {epoch}"
+                                    " finished, final checkpoint written, "
+                                    "exiting cleanly")
+                        break
+                    epoch += 1
+                except elastic_mod.DeviceLossError as err:
+                    if not el_on:
+                        raise
+                    if recoveries >= elastic.max_recoveries:
+                        raise
+                    if not ckpt_dir:
+                        raise RuntimeError(
+                            "elastic recovery needs a checkpoint to restore "
+                            "from — run with ckpt_dir + checkpoint_every"
+                        ) from err
+                    with TraceAnnotation("trainer/recover"):
+                        from repro.checkpoint import (latest_step,
+                                                      restore_checkpoint)
+                        last = latest_step(ckpt_dir)
+                        if last is None:
+                            raise RuntimeError(
+                                "device lost before the first checkpoint "
+                                "landed — nothing to recover from") from err
+                        if not err.survivors:
+                            raise RuntimeError(
+                                "no surviving devices to remap onto") from err
+                        plan = ElasticPlan(num_parts=P,
+                                           orig_devices=orig_devices,
+                                           survivors=err.survivors)
+                        state = restore_checkpoint(ckpt_dir, last,
+                                                   flat_template())
+                        params, opt_state = (state["params"],
+                                             state["opt_state"])
+                        key = state["key"]
+                        buffers = apply_plan_state(state["buffers"], plan)
+                        epoch = int(state["epoch"])
+                        topo_run = elastic_mod.remap_topology(topo, plan)
+                        train_run = elastic_mod.remap_data(
+                            pipeline.train_data, plan)
+                        val_run = elastic_mod.remap_data(pipeline.val_data,
+                                                         plan)
+                        cur_survivors = plan.survivors
+                        cur_n_local = plan.n_local
+                        if mesh0 is not None:
+                            from repro.launch.mesh import make_survivor_mesh
+                            use_mesh(make_survivor_mesh(plan, axis_name))
+                        tables = build_tables(plan)
+                        recoveries += 1
+                        consec = 0
+                        anomalies["device_losses"].append({
+                            "device": err.device,
+                            "detected_epoch": err.epoch,
+                            "resumed_from": int(last),
+                            "survivors": list(plan.survivors)})
                     if log:
-                        log(f"preempted (signal {int(stop_signals[0])}): "
-                            f"epoch {epoch} finished, final checkpoint "
-                            "written, exiting cleanly")
-                    break
-                epoch += 1
-            except elastic_mod.DeviceLossError as err:
-                if not el_on:
-                    raise
-                if recoveries >= elastic.max_recoveries:
-                    raise
-                if not ckpt_dir:
-                    raise RuntimeError(
-                        "elastic recovery needs a checkpoint to restore "
-                        "from — run with ckpt_dir + checkpoint_every"
-                    ) from err
-                from repro.checkpoint import latest_step, restore_checkpoint
-                last = latest_step(ckpt_dir)
-                if last is None:
-                    raise RuntimeError(
-                        "device lost before the first checkpoint landed — "
-                        "nothing to recover from") from err
-                if not err.survivors:
-                    raise RuntimeError(
-                        "no surviving devices to remap onto") from err
-                plan = ElasticPlan(num_parts=P, orig_devices=orig_devices,
-                                   survivors=err.survivors)
-                state = restore_checkpoint(ckpt_dir, last, flat_template())
-                params, opt_state = state["params"], state["opt_state"]
-                key = state["key"]
-                buffers = apply_plan_state(state["buffers"], plan)
-                epoch = int(state["epoch"])
-                topo_run = elastic_mod.remap_topology(topo, plan)
-                train_run = elastic_mod.remap_data(pipeline.train_data, plan)
-                val_run = elastic_mod.remap_data(pipeline.val_data, plan)
-                cur_survivors = plan.survivors
-                cur_n_local = plan.n_local
-                if mesh0 is not None:
-                    from repro.launch.mesh import make_survivor_mesh
-                    use_mesh(make_survivor_mesh(plan, axis_name))
-                tables = build_tables(plan)
-                recoveries += 1
-                consec = 0
-                anomalies["device_losses"].append({
-                    "device": err.device, "detected_epoch": err.epoch,
-                    "resumed_from": int(last),
-                    "survivors": list(plan.survivors)})
-                if log:
-                    log(f"device {err.device} lost at epoch {err.epoch}: "
-                        f"remapped {P} partitions onto survivors "
-                        f"{list(plan.survivors)} ({plan.n_local}/device, "
-                        f"{plan.pad_parts} pad), restored checkpoint step "
-                        f"{last}, resuming at epoch {epoch}")
+                        with TraceAnnotation("trainer/log"):
+                            log(f"device {err.device} lost at epoch "
+                                f"{err.epoch}: remapped {P} partitions onto "
+                                f"survivors {list(plan.survivors)} "
+                                f"({plan.n_local}/device, {plan.pad_parts} "
+                                f"pad), restored checkpoint step {last}, "
+                                f"resuming at epoch {epoch}")
     finally:
         for signum, h in sig_handlers.items():
             signal.signal(signum, h)
@@ -653,8 +702,8 @@ def train_pipegcn(pipeline, model_cfg: ModelConfig,
         final = last_metric    # the last epoch already ran this eval
     else:
         final = pipeline.metric(fwd(topo_run, params, val_run))
-    ran = max(epochs - start_epoch, 0)
     return TrainResult(history=history, params=params, final_metrics=final,
-                       epochs_per_sec=ran / dt if dt > 0 and ran else 0.0,
+                       epochs_per_sec=(finished / dt if dt > 0 and finished
+                                       else 0.0),
                        anomalies=anomalies, resumed_from=resumed_from,
                        recoveries=recoveries, preempted=preempted)

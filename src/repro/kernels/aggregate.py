@@ -39,11 +39,26 @@ with_tiles=True)`` or ``GraphDataPipeline.build(..., agg="blocksparse")``).
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops
 from repro.kernels.gcn_spmm import FEAT_BLOCK, TILE
+
+
+def _scoped(method):
+    """Trace an engine method under the `aggregate` named scope (see
+    `repro.core.trace_utils`), so that its ops, a fused kernel's epilogue
+    included, carry it in their HLO metadata."""
+    @functools.wraps(method)
+    def run(self, *args, **kwargs):
+        # imported here: importing repro.core imports this module
+        from repro.core.trace_utils import AGGREGATE
+        with jax.named_scope(AGGREGATE):
+            return method(self, *args, **kwargs)
+    return run
 
 
 def _ceil_to(n: int, m: int) -> int:
@@ -87,7 +102,8 @@ class AggregationEngine:
         """u = (P·comb) @ w + b (optionally ReLU'd), plus the aggregation
         residual z = P·comb (None when `with_z=False`, e.g. at eval).
         Default: compose the primitive SpMM with a dense matmul — the
-        (num_rows, F_in) intermediate round-trips through HBM."""
+        (num_rows, F_in) intermediate round-trips through HBM; the matmul
+        stays in the caller's named scope, out of `aggregate`."""
         z = self.spmm(tslice, comb, num_rows)
         u = z @ w + b
         if relu:
@@ -120,11 +136,13 @@ class CooEngine(AggregationEngine):
     name = "coo"
     fields = ("edge_row", "edge_col", "edge_w")
 
+    @_scoped
     def spmm(self, tslice, comb, num_rows: int):
         edge_row, edge_col, edge_w = tslice
         vals = comb[edge_col] * edge_w[:, None]
         return jax.ops.segment_sum(vals, edge_row, num_segments=num_rows)
 
+    @_scoped
     def spmm_t(self, tslice, dz, num_cols: int):
         edge_row, edge_col, edge_w = tslice
         vals = dz[edge_row] * edge_w[:, None]
@@ -137,12 +155,14 @@ class CooEngine(AggregationEngine):
     # tile engines' sliced streams, which is what the SPMD parity matrix
     # gates on. Out-of-phase rows come out zero (a valid value for
     # "unspecified").
+    @_scoped
     def spmm_phased(self, tslice, comb, num_rows: int, split, phase: str):
         edge_row, edge_col, edge_w = tslice
         keep = _phase_mask(edge_row >= split.row_tail, phase)
         vals = comb[edge_col] * jnp.where(keep, edge_w, 0)[:, None]
         return jax.ops.segment_sum(vals, edge_row, num_segments=num_rows)
 
+    @_scoped
     def spmm_t_phased(self, tslice, dz, num_cols: int, split, phase: str):
         edge_row, edge_col, edge_w = tslice
         keep = _phase_mask(edge_col >= split.col_tail, phase)
@@ -171,6 +191,7 @@ class BlockSparseEngine(AggregationEngine):
         tile_vals = tslice[2]
         return tile_vals.astype(like.dtype)
 
+    @_scoped
     def spmm(self, tslice, comb, num_rows: int):
         tile_rows, tile_cols = tslice[:2]
         combined, f = comb.shape
@@ -182,6 +203,7 @@ class BlockSparseEngine(AggregationEngine):
         assert z.shape == (rpad, fpad), (z.shape, rpad, fpad)
         return z[:num_rows, :f]
 
+    @_scoped
     def spmm_t(self, tslice, dz, num_cols: int):
         t_out, t_in, t_perm = tslice[3:]
         num_rows, f = dz.shape
@@ -199,6 +221,7 @@ class BlockSparseEngine(AggregationEngine):
     # so each phase's own rows are BITWISE the unsplit result — same
     # tiles, same accumulation order. Out-of-phase rows are unwritten
     # kernel output (garbage, never to be read).
+    @_scoped
     def spmm_phased(self, tslice, comb, num_rows: int, split, phase: str):
         tile_rows, tile_cols = tslice[:2]
         combined, f = comb.shape
@@ -210,6 +233,7 @@ class BlockSparseEngine(AggregationEngine):
         assert z.shape == (rpad, fpad), (z.shape, rpad, fpad)
         return z[:num_rows, :f]
 
+    @_scoped
     def spmm_t_phased(self, tslice, dz, num_cols: int, split, phase: str):
         t_out, t_in, t_perm = tslice[3:]
         num_rows, f = dz.shape
@@ -237,6 +261,7 @@ class FusedBlockSparseEngine(BlockSparseEngine):
 
     name = "fused"
 
+    @_scoped
     def aggregate_transform(self, tslice, comb, w, b, num_rows: int,
                             relu: bool = False, with_z: bool = True):
         tile_rows, tile_cols = tslice[:2]
@@ -257,6 +282,7 @@ class FusedBlockSparseEngine(BlockSparseEngine):
             z = z[:num_rows, :fin]
         return u, z
 
+    @_scoped
     def aggregate_transform_t(self, tslice, du, w, num_cols: int):
         t_out, t_in, t_perm = tslice[3:]
         num_rows, fout = du.shape
