@@ -1,4 +1,10 @@
-"""Configuration dataclasses for the PipeGCN core."""
+"""Configuration dataclasses for the PipeGCN core.
+
+Every aggregation engine computes the layer's f32 sums at f32 accuracy:
+COO sums edges in f32, and the tile kernels contract f32 operands at
+`Precision.HIGHEST`. `ModelConfig.agg` defaults to "auto", which the
+graph pipeline resolves to one of them from the platform and the tile
+density (`repro.kernels.aggregate.choose_engine`)."""
 from __future__ import annotations
 
 import dataclasses
@@ -16,10 +22,14 @@ class ModelConfig:
     dropout: float = 0.5
     multilabel: bool = False       # sigmoid BCE (Yelp) vs softmax CE
     # Aggregation engine for the Eq. 3/4 SpMM: "coo" (segment_sum fallback),
-    # "blocksparse" (Pallas MXU kernels; Topology must carry tiles), or
+    # "blocksparse" (Pallas MXU kernels; Topology must carry tiles),
     # "fused" (blocksparse tiles + single-pass aggregate⊗transform kernels
-    # with the dense weight contracted in the same grid pass).
-    agg: str = "coo"
+    # with the dense weight contracted in the same grid pass), or "auto"
+    # (the default): train_pipegcn runs the engine the pipeline resolved
+    # (GraphDataPipeline.agg: COO off the TPU; on it the tile engine where
+    # the tiles are estimated faster and fit). A PipeGCN built on "auto"
+    # without a pipeline runs COO — there are no tiles to run on.
+    agg: str = "auto"
     # Matmul ordering of the layer pair P·H·W (Demirci et al.: a first-order
     # FLOP knob — P·(H·W) costs 2·nnz·F_out where (P·H)·W costs 2·nnz·F_in):
     #   "aggregate-first"  z = P·H, then u = z·W   (the paper's Eq. 3 order)

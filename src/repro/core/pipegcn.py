@@ -545,8 +545,11 @@ class PipeGCN:
 
     @property
     def engine(self):
-        """The aggregation engine selected by ``ModelConfig.agg``."""
-        return get_engine(self.model.agg)
+        """The aggregation engine selected by ``ModelConfig.agg`` ("auto",
+        unless `train_pipegcn` put the pipeline's engine in its place,
+        runs COO)."""
+        return get_engine("coo" if self.model.agg == "auto"
+                          else self.model.agg)
 
     def _agg_slice(self, topo: Topology):
         """The Topology fields the selected engine consumes (still carrying

@@ -245,6 +245,15 @@ def train_pipegcn(pipeline, model_cfg: ModelConfig,
     Preemption: SIGTERM/SIGINT (main thread only) finishes the in-flight
     epoch, writes a final checkpoint (when checkpointing is configured),
     and returns cleanly with `TrainResult.preempted=True`."""
+    # "auto" defers to the engine the pipeline resolved (and built tiles
+    # for); an explicit engine is used as given.
+    if model_cfg.agg == "auto":
+        model_cfg = dataclasses.replace(
+            model_cfg, agg=getattr(pipeline, "agg", "coo"))
+        choice = getattr(pipeline, "agg_choice",
+                         {"agg": model_cfg.agg, "how": "forced"})
+    else:
+        choice = {"agg": model_cfg.agg, "how": "forced"}
     split = pipeline.split_spec() if hasattr(pipeline, "split_spec") else None
     model = PipeGCN(model_cfg, pipe_cfg, split=split)
     topo = pipeline.topo
@@ -265,6 +274,8 @@ def train_pipegcn(pipeline, model_cfg: ModelConfig,
             "GraphDataPipeline.build (or use layout=\"auto\")")
     if log:
         from repro.core.trace_utils import expected_boundary_collectives
+        from repro.kernels.aggregate import describe_choice
+        log(f"aggregation engine: {describe_choice(choice)}")
         n_coll = expected_boundary_collectives(model_cfg.num_layers,
                                                pipe_cfg.fused, train=True)
         sched = "fused-deferred" if pipe_cfg.fused else "per-layer"

@@ -5,7 +5,10 @@ The `agg` knob mirrors ``ModelConfig.agg``: building with
 ``agg="blocksparse"`` or ``agg="fused"`` additionally extracts the
 per-partition block-sparse tile streams onto the Topology, so any
 aggregation engine can run on the same partitioned graph (the COO shards
-are always present)."""
+are always present). ``agg="auto"``, the default, resolves here, once, to
+a concrete engine (`repro.kernels.aggregate.choose_engine`): COO off the
+TPU, and on it the tile engine where the partitions' tile slots are
+estimated faster than their padded edge rows and the tiles fit."""
 from __future__ import annotations
 
 import dataclasses
@@ -16,9 +19,11 @@ import numpy as np
 
 from repro.core.pipegcn import ShardedData, Topology, shard_data, topology_from
 from repro.graph.csr import mean_normalized, sym_normalized
-from repro.graph.halo import PartitionedGraph, build_partitioned_graph
+from repro.graph.halo import (PartitionedGraph, build_partitioned_graph,
+                              tile_slot_count)
 from repro.graph.partition import partition_graph
 from repro.graph.synthetic import GraphDataset, make_dataset
+from repro.kernels.aggregate import AUTO_TILE_ENGINE, choose_engine
 
 
 def to_local_layout(tree, n_local: int, axis: int = 0):
@@ -52,6 +57,35 @@ def from_local_layout(tree, axis: int = 0):
     return jax.tree.map(r, tree)
 
 
+def device_bytes_limit() -> int | None:
+    """Memory of JAX's first device, where its backend reports one."""
+    stats = jax.devices()[0].memory_stats()
+    return stats.get("bytes_limit") if stats else None
+
+
+def resolve_agg(prop, part, num_parts: int, layout: str = "auto",
+                platform: str | None = None,
+                bytes_limit: int | None = None):
+    """Resolve `agg="auto"`: the `choose_engine` dict, and the partitioned
+    graph whose tile slots and padded edge rows it counted, in the layout
+    the tile engine would use (None off the TPU, where nothing is
+    counted). `platform` defaults to JAX's backend and `bytes_limit` to
+    the first device's memory; tests pass both."""
+    from repro.graph.reorder import resolve_layout
+    platform = platform or jax.default_backend()
+    if platform != "tpu":
+        return choose_engine(platform), None
+    pg = build_partitioned_graph(
+        prop, part, num_parts,
+        layout=resolve_layout(layout, AUTO_TILE_ENGINE))
+    if bytes_limit is None:
+        bytes_limit = device_bytes_limit()
+    return choose_engine(platform, parts=num_parts,
+                         slots=tile_slot_count(pg),
+                         edge_rows=num_parts * pg.max_nnz,
+                         bytes_limit=bytes_limit), pg
+
+
 @dataclasses.dataclass
 class GraphDataPipeline:
     """Device-ready view of one partitioned graph dataset: the Topology,
@@ -66,31 +100,50 @@ class GraphDataPipeline:
     train_data: ShardedData
     val_data: ShardedData
     test_data: ShardedData
-    agg: str = "coo"
+    agg: str = "coo"               # resolved engine ("auto" never stored)
     layout: str = "natural"        # resolved node layout ("auto" never stored)
+    # what `agg` was resolved from: "how" ("auto" | "forced"), and for
+    # "auto" the platform, the reason and the counts behind the choice
+    # (`repro.kernels.aggregate.choose_engine`)
+    agg_choice: dict = dataclasses.field(
+        default_factory=lambda: {"agg": "coo", "how": "forced"})
 
     @staticmethod
     def build(name_or_ds, num_parts: int, kind: str = "sage",
               seed: int = 0, partition_method: str = "bfs+refine",
-              agg: str = "coo", layout: str = "auto") -> "GraphDataPipeline":
-        """`layout` picks the intra-partition node order ("natural" | "rcm"
-        | "auto"): "rcm" applies the bandwidth-reducing + halo-clustering
-        permutation of repro.graph.reorder — fewer nonempty tiles for the
-        block-sparse engines, numerically invisible everywhere — and
-        "auto" (the default, matching ModelConfig.layout and the CLI)
-        resolves to "rcm" exactly when the selected aggregation engine
-        consumes tiles. Features/labels/masks are remapped ONCE here
-        (pack_nodes routes through the reordered local_of); results are
-        unpermuted only at the eval/metric boundary (`metric` goes
-        through unpack_nodes)."""
+              agg: str = "auto", layout: str = "auto") -> "GraphDataPipeline":
+        """`agg` picks the aggregation engine ("auto" | "coo" |
+        "blocksparse" | "fused"); "auto" (the default, matching
+        ModelConfig.agg and the CLI) is resolved by `resolve_agg`: by
+        JAX's backend, the tile slots and padded edge rows of the
+        partitioned graph, counted in the layout the tile engine would
+        use, and the first device's memory. `layout` picks the
+        intra-partition node order ("natural" | "rcm" | "auto"): "rcm"
+        applies the
+        bandwidth-reducing + halo-clustering permutation of
+        repro.graph.reorder — fewer nonempty tiles for the block-sparse
+        engines, numerically invisible everywhere — and "auto" (the
+        default, matching ModelConfig.layout and the CLI) resolves to
+        "rcm" exactly when the resolved aggregation engine consumes
+        tiles. Features/labels/masks are remapped ONCE here (pack_nodes
+        routes through the reordered local_of); results are unpermuted
+        only at the eval/metric boundary (`metric` goes through
+        unpack_nodes)."""
         ds = (make_dataset(name_or_ds) if isinstance(name_or_ds, str)
               else name_or_ds)
         from repro.graph.reorder import TILE_ENGINES, resolve_layout
-        layout = resolve_layout(layout, agg)
         prop = mean_normalized(ds.graph) if kind == "sage" else sym_normalized(ds.graph)
         part = partition_graph(ds.graph, num_parts, seed=seed,
                                method=partition_method)
-        pg = build_partitioned_graph(prop, part, num_parts, layout=layout)
+        pg = None
+        if agg == "auto":
+            choice, pg = resolve_agg(prop, part, num_parts, layout)
+            agg = choice["agg"]
+        else:
+            choice = {"agg": agg, "how": "forced"}
+        layout = resolve_layout(layout, agg)
+        if pg is None or pg.layout != layout:
+            pg = build_partitioned_graph(prop, part, num_parts, layout=layout)
         topo = topology_from(pg, with_tiles=(agg in TILE_ENGINES))
         # x/labels/train_mask are split-independent: pack them ONCE and share
         # the arrays across the three views; only eval_mask differs per split.
@@ -102,7 +155,7 @@ class GraphDataPipeline:
             val_data=base,
             test_data=base._replace(
                 eval_mask=jnp.asarray(pg.pack_nodes(np.asarray(ds.test_mask)))),
-            agg=agg, layout=layout)
+            agg=agg, layout=layout, agg_choice=choice)
 
     def split_spec(self):
         """`SplitSpec` of this pipeline's partitioned graph for the

@@ -165,6 +165,47 @@ def boundary_row_split(pg: "PartitionedGraph", tile: int = 128) -> dict:
             and b0 * tile < pg.max_inner}
 
 
+def _stream_lengths(blocks, split: dict):
+    """Padded length of the partitions' tile streams, from each one's
+    (row block, col block) arrays, and, where the phase split is feasible,
+    the uniform group lengths (fwd interior, fwd boundary, transpose
+    interior, transpose boundary) the phase-aware padding pads to (None
+    otherwise)."""
+    sizes = [len(r) for r, _ in blocks]
+    if not split["feasible"]:
+        return max(sizes), None
+    cuts_f = [int(np.count_nonzero(r < split["b0"])) for r, _ in blocks]
+    cuts_t = [int(np.count_nonzero(c < split["hb0"])) for _, c in blocks]
+    n_int_f = max(cuts_f)
+    n_bnd_f = max(n - c for n, c in zip(sizes, cuts_f))
+    n_int_t = max(cuts_t)
+    n_bnd_t = max(n - c for n, c in zip(sizes, cuts_t))
+    # Both streams of one partition share the vals storage, so their
+    # padded totals must agree; absorb the difference into the larger
+    # schedule's interior group (pads there are cheapest to place).
+    n_tiles = max(n_int_f + n_bnd_f, n_int_t + n_bnd_t)
+    n_int_f += n_tiles - (n_int_f + n_bnd_f)
+    n_int_t += n_tiles - (n_int_t + n_bnd_t)
+    return n_tiles, (n_int_f, n_bnd_f, n_int_t, n_bnd_t)
+
+
+def tile_slot_count(pg: "PartitionedGraph", tile: int | None = None) -> int:
+    """Length of each partition's padded tile stream, the `n_tiles` that
+    `extract_partition_tiles` gives, counted from the shards' indices
+    alone (one `np.unique` of block keys per partition): no tile values
+    are made, so an engine choice can price the tiles before building
+    them."""
+    from repro.kernels.gcn_spmm import TILE, tile_blocks
+    tile = TILE if tile is None else tile
+    cached = pg.tile_cache.get(tile)
+    if cached is not None:
+        return cached.n_tiles
+    blocks = [tile_blocks(pg.edge_row[i], pg.edge_col[i], pg.edge_w[i],
+                          pg.max_inner, pg.combined, tile)
+              for i in range(pg.num_parts)]
+    return _stream_lengths(blocks, boundary_row_split(pg, tile))[0]
+
+
 def extract_partition_tiles(pg: "PartitionedGraph",
                             tile: int | None = None) -> PartitionTiles:
     """Per-partition TILE×TILE tile extraction for the blocksparse engine.
@@ -200,26 +241,14 @@ def extract_partition_tiles(pg: "PartitionedGraph",
                                pg.max_inner, pg.combined, tile)
            for i in range(pg.num_parts)]
     split = boundary_row_split(pg, tile)
+    n_tiles, groups = _stream_lengths([(tt.rows, tt.cols) for tt in per],
+                                      split)
     meta: dict = dict(b0=None, hb0=None, fwd_bnd=None, t_bnd=None)
-    if split["feasible"]:
+    if groups is not None:
         b0, hb0 = split["b0"], split["hb0"]
-        cuts_f = [int(np.searchsorted(tt.rows, b0)) for tt in per]
-        cuts_t = [int(np.searchsorted(tt.t_out, hb0)) for tt in per]
-        n_int_f = max(cuts_f)
-        n_bnd_f = max(tt.n_tiles - c for tt, c in zip(per, cuts_f))
-        n_int_t = max(cuts_t)
-        n_bnd_t = max(tt.n_tiles - c for tt, c in zip(per, cuts_t))
-        # Both streams of one partition share the vals storage, so their
-        # padded totals must agree; absorb the difference into the larger
-        # schedule's interior group (pads there are cheapest to place).
-        n_tiles = max(n_int_f + n_bnd_f, n_int_t + n_bnd_t)
-        n_int_f += n_tiles - (n_int_f + n_bnd_f)
-        n_int_t += n_tiles - (n_int_t + n_bnd_t)
-        per = [pad_tile_topology_phased(tt, b0, hb0, n_int_f, n_bnd_f,
-                                        n_int_t, n_bnd_t) for tt in per]
-        meta = dict(b0=b0, hb0=hb0, fwd_bnd=n_bnd_f, t_bnd=n_bnd_t)
+        per = [pad_tile_topology_phased(tt, b0, hb0, *groups) for tt in per]
+        meta = dict(b0=b0, hb0=hb0, fwd_bnd=groups[1], t_bnd=groups[3])
     else:
-        n_tiles = max(tt.n_tiles for tt in per)
         per = [pad_tile_topology(tt, n_tiles) for tt in per]
     out = PartitionTiles(
         rows=np.stack([tt.rows for tt in per]),

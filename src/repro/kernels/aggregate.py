@@ -33,9 +33,16 @@ in which the intermediate never leaves VMEM. Three implementations:
               in the caller's dtype (f32 in production; f64 under the x64
               exactness tests, where it matches "coo" to 1e-12).
 
-Select with ``ModelConfig.agg`` ("coo" | "blocksparse" | "fused"); the tile
-engines need tile fields on the Topology (``topology_from(pg,
-with_tiles=True)`` or ``GraphDataPipeline.build(..., agg="blocksparse")``).
+Every engine computes the layer's f32 sums at f32 accuracy: COO sums
+edges in f32, and the tile kernels contract f32 operands at
+`Precision.HIGHEST`.
+
+Select with ``ModelConfig.agg`` ("auto" | "coo" | "blocksparse" |
+"fused"); the tile engines need tile fields on the Topology
+(``topology_from(pg, with_tiles=True)`` or ``GraphDataPipeline.build(...,
+agg="blocksparse")``). "auto", the default, is resolved once by the graph
+pipeline through `choose_engine` below: COO off the TPU, and on it the
+tile engine where the tiles are estimated faster and fit.
 """
 from __future__ import annotations
 
@@ -300,6 +307,81 @@ class FusedBlockSparseEngine(BlockSparseEngine):
 
 ENGINES = {e.name: e for e in (CooEngine(), BlockSparseEngine(),
                                FusedBlockSparseEngine())}
+
+#: The tile engine "auto" names when the tiles win.
+AUTO_TILE_ENGINE = "fused"
+#: Device time per 128-column feature block of one aggregation, on a TPU
+#: v5e: per padded tile slot of the tile engine, and per padded edge row
+#: of the COO engine. Each is the `aggregate` scope's device time in a
+#: traced window of the reddit-sage.pipegcn-gf.1chip benchmark cell run
+#: under that engine, over units x feature-block passes in the window:
+#: tiles 8,372.6 ms / (16,220 slots x 959) (seed 2147494001), COO
+#: 9,561.7 ms / (1,630,304 rows x 436) (seed 2147494003); PERF.md has the
+#: runs. They break even at 40 padded edges per slot.
+C_TILE_S = 0.54e-6
+C_COO_S = 13.5e-9
+#: Largest share of one device's memory the tile values may take: the
+#: step also holds the features, the activations and per-call copies of
+#: the tiles.
+TILE_MEMORY_SHARE = 0.25
+
+
+def choose_engine(platform: str, parts: int = 0, slots: int = 0,
+                  edge_rows: int = 0, bytes_limit: int | None = None) -> dict:
+    """The engine `agg="auto"` runs, and the numbers it was chosen on.
+
+    The tile engine only where all three hold: the platform is a TPU (off
+    it the kernels are interpreted, so the counts are not even needed);
+    the tiles' estimated time, `parts x slots x C_TILE_S`, is below the
+    COO engine's, `edge_rows x C_COO_S` (both per feature block, which
+    both engines stream alike); and the tile values, `parts x slots x
+    TILE^2 x 4` bytes, take at most TILE_MEMORY_SHARE of `bytes_limit`,
+    the device's memory (unknown: no tiles). `slots` is one partition's
+    padded tile stream, `edge_rows` the padded COO rows of all
+    partitions.
+
+    A mesh runs the same rule. Both of its departures there err toward
+    COO: C_TILE_S includes the per-partition slices of `tile_vals` that
+    the vmapped step pays and a mesh with one partition per device does
+    not, and the memory test counts every partition's tiles against one
+    device where a mesh spreads them over its devices."""
+    choice = {"agg": "coo", "how": "auto", "platform": platform}
+    if platform != "tpu":
+        choice["why"] = "the tile kernels run compiled only on a TPU"
+        return choice
+    tile_ms = parts * slots * C_TILE_S * 1e3
+    coo_ms = edge_rows * C_COO_S * 1e3
+    tile_bytes = parts * slots * TILE * TILE * 4
+    choice.update(tile_slots=parts * slots, edge_rows=edge_rows,
+                  tile_ms=tile_ms, coo_ms=coo_ms, tile_bytes=tile_bytes,
+                  bytes_limit=bytes_limit)
+    if tile_ms >= coo_ms:
+        choice["why"] = "the tiles are estimated slower"
+    elif (bytes_limit is None
+          or tile_bytes > TILE_MEMORY_SHARE * bytes_limit):
+        choice["why"] = "the tile values would not fit"
+    else:
+        choice.update(agg=AUTO_TILE_ENGINE,
+                      why="the tiles are estimated faster and fit")
+    return choice
+
+
+def describe_choice(choice: dict) -> str:
+    """One line for the trainer's log: the engine, and for `auto` the
+    counts and estimates it was chosen on."""
+    if choice["how"] != "auto":
+        return f"{choice['agg']} ({choice['how']})"
+    line = f"{choice['agg']} (auto on {choice['platform']}: {choice['why']}"
+    if "tile_ms" in choice:
+        limit = choice["bytes_limit"]
+        line += (f"; {choice['tile_slots']:,} tile slots x "
+                 f"{C_TILE_S * 1e6:g} us = {choice['tile_ms']:.4g} ms vs "
+                 f"{choice['edge_rows']:,} padded edge rows x "
+                 f"{C_COO_S * 1e9:g} ns = {choice['coo_ms']:.4g} ms per "
+                 f"feature block; tile values {choice['tile_bytes']:,} B vs "
+                 + (f"{TILE_MEMORY_SHARE:.0%} of {limit:,} B" if limit
+                    else "an unknown device memory"))
+    return line + ")"
 
 
 def get_engine(name: str):

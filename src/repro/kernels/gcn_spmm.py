@@ -16,6 +16,11 @@ reduction pattern. Tile coordinates arrive via scalar prefetch
 (PrefetchScalarGridSpec) so the index stream is resident before the DMA of
 each tile.
 
+Every dot whose operands are f32 contracts at `Precision.HIGHEST`
+(`#tpu.contract_precision<fp32>`, see `_precision`), so the kernels compute
+the same f32 sums as the COO engine's `segment_sum` to f32 rounding, not
+one bf16 pass of the MXU, which is Mosaic's default.
+
 The transpose kernel (`spmm_block_sparse_t`) reuses the SAME tile values:
 it walks the tiles in a column-major order (a prefetched permutation into
 `tile_vals`) and contracts each tile transposed (dot_general over dim 0),
@@ -82,6 +87,18 @@ def resolve_interpret(interpret: bool | None) -> bool:
         "agg='coo' there, or pass interpret=True explicitly")
 
 
+def _precision(*dtypes):
+    """Contract precision of a kernel dot: `Precision.HIGHEST` when every
+    operand is f32, which Mosaic lowers to `#tpu.contract_precision<fp32>`
+    (an f32-accurate product, as the COO engine's f32 sums are); the
+    default otherwise. Mosaic's default contracts f32 operands in one bf16
+    pass. bf16 operands need no more; f64 runs only in interpret mode,
+    which computes it exactly either way."""
+    if all(jnp.dtype(d) == jnp.float32 for d in dtypes):
+        return jax.lax.Precision.HIGHEST
+    return None
+
+
 def _acc_dtype(dtype) -> jnp.dtype:
     """VMEM accumulator dtype: f32 for f32/bf16 inputs (MXU-native), f64
     when the caller runs in f64 (interpret mode only — used by the exactness
@@ -106,6 +123,7 @@ def _kernel(rows_ref, cols_ref, vals_ref, h_ref, out_ref, acc_ref):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     acc_ref[...] += jnp.dot(vals_ref[...], h_ref[...],
+                            precision=_precision(vals_ref.dtype, h_ref.dtype),
                             preferred_element_type=acc_ref.dtype)
 
     last = t == pl.num_programs(1) - 1
@@ -178,6 +196,7 @@ def _kernel_t(out_ref_s, in_ref_s, perm_ref, vals_ref, dz_ref, out_ref,
     acc_ref[...] += jax.lax.dot_general(
         vals_ref[...], dz_ref[...],
         dimension_numbers=(((0,), (0,)), ((), ())),
+        precision=_precision(vals_ref.dtype, dz_ref.dtype),
         preferred_element_type=acc_ref.dtype)
 
     last = t == pl.num_programs(1) - 1
@@ -360,6 +379,7 @@ def _kernel_fused(rows_ref, cols_ref, vals_ref, h_ref, w_ref, b_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     acc_ref[...] += jnp.dot(vals_ref[...], h_ref[...],
+                            precision=_precision(vals_ref.dtype, h_ref.dtype),
                             preferred_element_type=acc_ref.dtype)
 
     last = t == pl.num_programs(0) - 1
@@ -370,6 +390,7 @@ def _kernel_fused(rows_ref, cols_ref, vals_ref, h_ref, w_ref, b_ref,
     @pl.when(last_of_run)
     def _():
         u = jnp.dot(acc_ref[...], w_ref[...],
+                    precision=_precision(acc_ref.dtype, w_ref.dtype),
                     preferred_element_type=acc_ref.dtype) + b_ref[...]
         if relu:
             u = jnp.maximum(u, 0)
@@ -450,10 +471,12 @@ def _kernel_fused_t(out_ref_s, in_ref_s, perm_ref, vals_ref, du_ref, w_ref,
     dz = jax.lax.dot_general(           # (TILE, F_out) @ (F_in, F_out)ᵀ
         du_ref[...], w_ref[...],
         dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=_precision(du_ref.dtype, w_ref.dtype),
         preferred_element_type=acc_ref.dtype)
     acc_ref[...] += jax.lax.dot_general(
         vals_ref[...], dz,
         dimension_numbers=(((0,), (0,)), ((), ())),
+        precision=_precision(vals_ref.dtype, dz.dtype),
         preferred_element_type=acc_ref.dtype)
 
     last = t == pl.num_programs(0) - 1
@@ -569,10 +592,7 @@ def build_tile_topology(row, col, val, num_rows: int, num_cols: int,
     rows = (uk // ncb).astype(np.int32)
     cols = (uk % ncb).astype(np.int32)
 
-    # Zero fillers: one per empty row block (forward flush) and per empty
-    # column block (transpose flush).
-    fill_r = np.setdiff1d(np.arange(nrb, dtype=np.int32), rows)
-    fill_c = np.setdiff1d(np.arange(ncb, dtype=np.int32), cols)
+    fill_r, fill_c = _fillers(rows, cols, nrb, ncb)
     if len(fill_r) or len(fill_c):
         rows = np.concatenate([rows, fill_r,
                                np.zeros(len(fill_c), np.int32)])
@@ -598,6 +618,33 @@ def build_tile_topology(row, col, val, num_rows: int, num_cols: int,
     return TileTopology(rows=rows, cols=cols, vals=vals,
                         t_out=cols[t_perm], t_in=rows[t_perm], t_perm=t_perm,
                         num_row_blocks=nrb, num_col_blocks=ncb)
+
+
+def _fillers(rows, cols, nrb: int, ncb: int):
+    """Zero filler tiles: one per empty row block (so the forward kernel
+    flushes it) and one per empty column block (so the transpose kernel
+    does)."""
+    return (np.setdiff1d(np.arange(nrb, dtype=np.int32), rows),
+            np.setdiff1d(np.arange(ncb, dtype=np.int32), cols))
+
+
+def tile_blocks(row, col, val, num_rows: int, num_cols: int,
+                tile: int = TILE) -> tuple[np.ndarray, np.ndarray]:
+    """(row block, col block) of every tile `build_tile_topology` stores
+    for this COO triple, fillers included, in no particular order — its
+    stream before padding, from the indices alone: no tile values are
+    made."""
+    keep = np.asarray(val) != 0
+    row = np.asarray(row, np.int64)[keep]
+    col = np.asarray(col, np.int64)[keep]
+    nrb = -(-num_rows // tile)
+    ncb = -(-num_cols // tile)
+    uk = np.unique((row // tile) * ncb + (col // tile))
+    rows = (uk // ncb).astype(np.int32)
+    cols = (uk % ncb).astype(np.int32)
+    fill_r, fill_c = _fillers(rows, cols, nrb, ncb)
+    return (np.concatenate([rows, fill_r, np.zeros(len(fill_c), np.int32)]),
+            np.concatenate([cols, np.zeros(len(fill_r), np.int32), fill_c]))
 
 
 def _run_major_order(major, minor) -> np.ndarray:

@@ -5,7 +5,7 @@ Two workload kinds behind one CLI:
   GCN full-graph training (the paper):
     python -m repro.launch.train --workload gcn --dataset reddit-sim \
         --partitions 4 --variant pipegcn-gf --epochs 300 \
-        --agg blocksparse      # Pallas block-sparse aggregation engine
+        --agg fused            # force the Pallas tile engine (default auto)
 
   Transformer LM training (assigned archs, reduced or full config):
     python -m repro.launch.train --workload lm --arch qwen3-8b --reduced \
@@ -85,7 +85,7 @@ def run_gcn(args) -> dict:
            "partitions": args.partitions, "variant": args.variant,
            "spmd": bool(args.spmd),
            "parts_per_device": args.parts_per_device,
-           "agg": args.agg,
+           "agg": pipeline.agg,
            "matmul_order": args.matmul_order,
            "layout": pipeline.layout,
            "fuse_exchange": pc.fuse_exchange,
@@ -167,11 +167,17 @@ def main():
     ap.add_argument("--variant", default="pipegcn",
                     help="vanilla|pipegcn|pipegcn-g|pipegcn-f|pipegcn-gf")
     ap.add_argument("--gcn-kind", default="sage", choices=["sage", "gcn"])
-    ap.add_argument("--agg", default="coo",
-                    choices=["coo", "blocksparse", "fused"],
-                    help="aggregation engine for the Eq. 3/4 SpMM (fused = "
-                         "blocksparse tiles + single-pass aggregate+"
-                         "transform Pallas kernels)")
+    ap.add_argument("--agg", default="auto",
+                    choices=["auto", "coo", "blocksparse", "fused"],
+                    help="aggregation engine for the Eq. 3/4 SpMM: coo = "
+                         "segment_sum scatter; blocksparse = Pallas MXU "
+                         "tile kernels; fused = the same tiles with the "
+                         "dense transform in the kernel; auto = coo off "
+                         "the TPU, and on it fused where the tile slots "
+                         "are estimated faster than the padded edge rows "
+                         "and the tiles fit the device's memory (the "
+                         "trainer logs the counts); --agg coo forces the "
+                         "scatter path")
     ap.add_argument("--matmul-order", default="auto",
                     choices=["auto", "aggregate-first", "transform-first"],
                     help="layer contraction order for P·H·W: (P·H)·W costs "
